@@ -55,24 +55,23 @@ for name, frame in [("q1 (low NDV)", low), ("per-order (high NDV)", high)]:
 '''
 
 
-def run_fig(module: str, timeout: int = 1800) -> str:
+def _run(args, timeout: int) -> str:
+    """Run one child benchmark; a child that fails fails the run."""
     env = subprocess_env(ROOT, extra_pythonpath=[ROOT])
-    proc = subprocess.run([sys.executable, "-m", module], capture_output=True,
+    proc = subprocess.run([sys.executable, *args], capture_output=True,
                           text=True, timeout=timeout, env=env, cwd=str(ROOT))
     if proc.returncode != 0:
-        return f"{module},ERROR,{proc.stderr.strip().splitlines()[-1] if proc.stderr else 'unknown'}"
+        sys.stderr.write(proc.stderr)
+        raise SystemExit(f"{' '.join(args)[:80]}: exit {proc.returncode}")
     return proc.stdout.strip()
+
+
+def run_fig(module: str, timeout: int = 1800) -> str:
+    return _run(["-m", module], timeout)
 
 
 def run_explain(timeout: int = 1800) -> str:
-    env = subprocess_env(ROOT, extra_pythonpath=[ROOT])
-    proc = subprocess.run([sys.executable, "-c", EXPLAIN_SCRIPT],
-                          capture_output=True, text=True, timeout=timeout,
-                          env=env, cwd=str(ROOT))
-    if proc.returncode != 0:
-        return "explain ERROR: " + (proc.stderr.strip().splitlines()[-1]
-                                    if proc.stderr else "unknown")
-    return proc.stdout.strip()
+    return _run(["-c", EXPLAIN_SCRIPT], timeout)
 
 
 def main() -> None:

@@ -18,6 +18,9 @@ from repro.backends.local import LocalBackend
 from repro.core import Builder, verify
 from repro.core.passes import FuseKMeansStep, Parallelize
 from repro.core.types import F32, Tensor
+from repro.launch.cache import enable_compile_cache
+
+enable_compile_cache()
 
 n, d, k, iters = 1 << 15, 8, 16, 5
 rng = np.random.default_rng(0)

@@ -15,6 +15,9 @@ import numpy as np
 
 from repro.core.expr import col
 from repro.frontends.dataflow import Context, avg_, count_, sum_
+from repro.launch.cache import enable_compile_cache
+
+enable_compile_cache()
 
 # -- make a toy sales table ---------------------------------------------------
 rng = np.random.default_rng(0)

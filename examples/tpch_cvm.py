@@ -12,7 +12,10 @@ import time
 
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.relational import tpch
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--sf", type=float, default=0.005)

@@ -33,15 +33,18 @@ def emitter(opcode: str):
     return deco
 
 
-@dataclass
+@dataclass(kw_only=True)
 class EvalCtx:
     """Carries sources and backend knobs through evaluation."""
 
+    #: Pallas interpret mode, from ``repro.kernels.interpret_on`` for the
+    #: platform the backend runs on — no default, so no path can reach a
+    #: kernel with a stale one
+    interpret: bool
     sources: Dict[str, Any] = field(default_factory=dict)
     use_kernels: bool = False
     mesh: Any = None            # set by the SPMD backend
     axis: Optional[str] = None  # mesh axis inside shard_map bodies
-    interpret: bool = True      # pallas interpret mode (CPU container)
     #: traced executions install a dict here; tapped ops accumulate
     #: ``key → [occurrences, rows_in, rows_out]`` (rows are traced scalars
     #: under jit — returned from the compiled body, never host callbacks)
@@ -168,11 +171,6 @@ def _groupagg(ctx, ins, args):
                                 int(ins.param("max_groups")))]
 
 
-#: bucket counts beyond this skip the Pallas kernel (its per-block one-hot
-#: accumulator scales with num_buckets) and use the XLA segment reduction
-_KERNEL_MAX_BUCKETS = 4096
-
-
 @emitter("vec.GroupAggDirect")
 def _groupagg_direct(ctx, ins, args):
     (t,) = args
@@ -182,8 +180,10 @@ def _groupagg_direct(ctx, ins, args):
     domains = tuple(ins.param("key_domains"))
     nb = int(ins.param("num_buckets"))
     pred = ins.param("pred")
-    if ctx.use_kernels and nb <= _KERNEL_MAX_BUCKETS:
-        from ..kernels import ops as kops
+    # the kernel's one-hot block grows with the buckets: a plan whose VMEM
+    # estimate is over the limit takes the XLA segment reduction instead
+    from ..kernels import ops as kops
+    if ctx.use_kernels and kops.grouped_select_agg_fits(pred, keys, aggs, nb):
         return [kops.grouped_select_agg(t, pred, keys, aggs, mg, domains, nb,
                                         interpret=ctx.interpret)]
     return [rt.group_agg_direct(t, keys, aggs, mg, domains, nb, pred=pred)]
@@ -233,9 +233,11 @@ def _fused_join_group_agg(ctx, ins, args):
         num_buckets=int(ins.param("num_buckets")),
         pred=ins.param("pred"),
     )
-    if (ctx.use_kernels and kw["join_num_buckets"] <= _KERNEL_MAX_BUCKETS
-            and kw["num_buckets"] <= _KERNEL_MAX_BUCKETS):
-        from ..kernels import ops as kops
+    from ..kernels import ops as kops
+    if ctx.use_kernels and kops.grouped_join_agg_fits(
+            left, right, left_on=kw["left_on"], right_on=kw["right_on"],
+            join_num_buckets=kw["join_num_buckets"], keys=kw["keys"],
+            aggs=kw["aggs"], num_buckets=kw["num_buckets"], pred=kw["pred"]):
         return [kops.grouped_join_agg(left, right, interpret=ctx.interpret,
                                       **kw)]
     return [rt.fused_join_group_agg(left, right, **kw)]

@@ -49,10 +49,12 @@ class Compiled:
 class LocalBackend:
     name = "local"
 
-    def __init__(self, use_kernels: bool = False, interpret: bool = True,
-                 jit: bool = True) -> None:
+    def __init__(self, use_kernels: bool = False, jit: bool = True) -> None:
+        from ..kernels import interpret_on
+
         self.use_kernels = use_kernels
-        self.interpret = interpret
+        # kernels run where the jitted program runs: the default backend
+        self.interpret = interpret_on(jax.default_backend())
         self.jit = jit
 
     def compile(self, program: Program) -> Compiled:

@@ -48,18 +48,6 @@ def spmd_emitter(opcode: str):
     return deco
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """jax.shard_map moved out of jax.experimental (and check_vma was called
-    check_rep) across jax releases — paper over both spellings."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def _stack_split(v: Any, n: int) -> Any:
     """Split a value into a stacked leading worker dim (global view)."""
     if isinstance(v, VecTable):
@@ -154,8 +142,9 @@ def _mesh_execute(ctx, ins, args):
         return tuple(jax.tree_util.tree_map(lambda x: jnp.asarray(x)[None], o)
                      for o in outs)
 
-    shard_fn = _shard_map(body, mesh, in_specs,
-                          tuple(out_specs for _ in p.results))
+    shard_fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=tuple(out_specs for _ in p.results),
+                             check_vma=False)
     outs = shard_fn(*values)
     return list(outs)
 
@@ -275,9 +264,13 @@ class SpmdBackend:
     def __init__(self, mesh: Mesh, axis: str = "workers", use_kernels: bool = False,
                  collectives: bool = True, jit: bool = True,
                  rewrite: bool = True) -> None:
+        from ..kernels import interpret_on
+
         self.mesh = mesh
         self.axis = axis
         self.use_kernels = use_kernels
+        # kernels run on the mesh's devices, whatever the default backend
+        self.interpret = interpret_on(mesh.devices.flat[0].platform)
         self.collectives = collectives
         self.jit = jit
         # standalone use still rewrites here; the compilation driver runs the
@@ -292,12 +285,12 @@ class SpmdBackend:
 
         def run(sources: Dict[str, Any], *args: Any) -> List[Any]:
             ctx = EvalCtx(sources=sources, use_kernels=self.use_kernels,
-                          mesh=self.mesh)
+                          mesh=self.mesh, interpret=self.interpret)
             return evaluate_spmd_program(ctx, program, *args)
 
         def run_traced(sources: Dict[str, Any], *args: Any):
             ctx = EvalCtx(sources=sources, use_kernels=self.use_kernels,
-                          mesh=self.mesh, taps={})
+                          mesh=self.mesh, interpret=self.interpret, taps={})
             outs = evaluate_spmd_program(ctx, program, *args)
             return outs, ctx.taps
 

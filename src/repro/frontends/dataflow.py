@@ -332,12 +332,17 @@ class Context:
                       if a.dtype.kind in ("U", "S") else a)
         return out
 
-    def sources(self) -> Dict[str, Any]:
+    def sources(self, sharding: Any = None) -> Dict[str, Any]:
+        """Every registered table as a device-resident VecTable.
+
+        ``sharding`` places each column as it is built: for the spmd target,
+        ``NamedSharding(mesh, P(axis))`` gives each device its slice of the
+        rows instead of staging whole tables on the first device."""
         from ..relational.runtime import VecTable
 
         return {
             name: VecTable.from_numpy(self._physical_columns(name),
-                                      self.capacity(name))
+                                      self.capacity(name), sharding)
             for name, data in self.tables.items()
         }
 

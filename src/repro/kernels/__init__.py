@@ -2,8 +2,9 @@
 
 Each kernel ships as ``<name>.py`` (pl.pallas_call + explicit BlockSpec VMEM
 tiling), with ``ops.py`` as the jit'd public wrapper and ``ref.py`` as the
-pure-jnp oracle.  On this CPU container kernels run with ``interpret=True``;
-on TPU the same BlockSpecs bind to real VMEM tiles.
+pure-jnp oracle.  Whether a kernel runs under the Pallas interpreter is
+decided in one place, :func:`interpret_on`, from the platform of the devices
+it runs on: the CPU backend interprets, a TPU compiles it with Mosaic.
 
 Kernels:
   * fused_select_agg   — single-pass select+project+aggregate (TPC-H Q6 pipeline)
@@ -15,3 +16,9 @@ Kernels:
 """
 
 from . import ops, ref  # noqa: F401
+
+
+def interpret_on(platform: str) -> bool:
+    """Pallas interpret mode for kernels run on ``platform``: on only for the
+    CPU backend, which has no Mosaic; every accelerator compiles them."""
+    return platform == "cpu"

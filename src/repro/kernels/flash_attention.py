@@ -84,7 +84,7 @@ def flash_attention_p(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool = True, window: Optional[int] = None,
                       sm_scale: Optional[float] = None,
                       block_q: int = 128, block_k: int = 128,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool) -> jax.Array:
     """q: (B, Hq, S, D); k, v: (B, Hkv, S, D); Hq % Hkv == 0 → (B, Hq, S, D)."""
     b, hq, s, d = q.shape
     hkv = k.shape[1]

@@ -28,46 +28,48 @@ _NEG = -3.0e38
 _POS = 3.0e38
 
 
-def _kernel(pred: Expr, aggs: Tuple[AggSpec, ...], names: Tuple[str, ...], nblocks: int,
-            *refs):
+def _kernel(pred: Expr, aggs: Tuple[AggSpec, ...], names: Tuple[str, ...], *refs):
     col_refs, valid_ref, out_ref = refs[:-2], refs[-2], refs[-1]
     i = pl.program_id(0)
 
+    # one accumulator row per aggregate, updated through static row slices
+    # of the output ref (Mosaic has no scatter, so no ``.at[j]`` updates)
     @pl.when(i == 0)
     def _init():
-        init = jnp.zeros_like(out_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
         for j, a in enumerate(aggs):
-            if a.fn == "min":
-                init = init.at[j, :].set(_POS)
-            elif a.fn == "max":
-                init = init.at[j, :].set(_NEG)
-        out_ref[...] = init
+            if a.fn in ("min", "max"):
+                out_ref[j:j + 1, :] = jnp.full((1, LANES),
+                                               _POS if a.fn == "min" else _NEG,
+                                               jnp.float32)
 
     cols = {n: r[...] for n, r in zip(names, col_refs)}
     keep = valid_ref[...] & evaluate(pred, cols, jnp)
 
-    acc = out_ref[...]
     for j, a in enumerate(aggs):
+        row = out_ref[j:j + 1, :]
         if a.fn == "count":
-            part = jnp.sum(keep.astype(jnp.float32), axis=0)
-            acc = acc.at[j, :].add(part)
+            out_ref[j:j + 1, :] = row + jnp.sum(keep.astype(jnp.float32), axis=0,
+                                                keepdims=True)
             continue
         arr = evaluate(a.expr, cols, jnp).astype(jnp.float32)
         if a.fn == "sum":
-            acc = acc.at[j, :].add(jnp.sum(jnp.where(keep, arr, 0.0), axis=0))
+            part = jnp.sum(jnp.where(keep, arr, 0.0), axis=0, keepdims=True)
+            out_ref[j:j + 1, :] = row + part
         elif a.fn == "min":
-            acc = acc.at[j, :].min(jnp.min(jnp.where(keep, arr, _POS), axis=0))
+            part = jnp.min(jnp.where(keep, arr, _POS), axis=0, keepdims=True)
+            out_ref[j:j + 1, :] = jnp.minimum(row, part)
         elif a.fn == "max":
-            acc = acc.at[j, :].max(jnp.max(jnp.where(keep, arr, _NEG), axis=0))
+            part = jnp.max(jnp.where(keep, arr, _NEG), axis=0, keepdims=True)
+            out_ref[j:j + 1, :] = jnp.maximum(row, part)
         else:
             raise ValueError(a.fn)
-    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("pred", "aggs", "names", "block_rows", "interpret"))
 def fused_select_agg_p(cols: Tuple[jax.Array, ...], valid: jax.Array, *,
                        pred: Expr, aggs: Tuple[AggSpec, ...], names: Tuple[str, ...],
-                       block_rows: int = 512, interpret: bool = True) -> jax.Array:
+                       block_rows: int = 512, interpret: bool) -> jax.Array:
     """cols: tuple of (R, 128) arrays; valid: (R, 128) bool. Returns (n_aggs,)."""
     rows = valid.shape[0]
     assert rows % block_rows == 0, (rows, block_rows)
@@ -82,7 +84,7 @@ def fused_select_agg_p(cols: Tuple[jax.Array, ...], valid: jax.Array, *,
     out_spec = pl.BlockSpec((out_rows, LANES), lambda i: (0, 0))
 
     lane_acc = pl.pallas_call(
-        functools.partial(_kernel, pred, aggs, names, nblocks),
+        functools.partial(_kernel, pred, aggs, names),
         grid=(nblocks,),
         in_specs=in_specs,
         out_specs=out_spec,

@@ -20,10 +20,9 @@ gated bucket budgets.
 Layout matches ``grouped_select_agg``: probe columns reshaped to (R, 128)
 lanes, the grid walks row-blocks, outputs are (NBG_pad, 128) lane
 accumulators (count first, then one per agg).  The per-bucket build tables
-are (NBJ_pad, 1) blocks — scalar-per-bucket side inputs (interpret-mode
-friendly; a hardware port would pad them to the lane width).  Grid
-iterations on TPU are sequential, so read-modify-write accumulation is
-safe.
+are (NBJ_pad, 1) blocks — scalar-per-bucket side inputs, which Mosaic
+pads to the lane width in VMEM.  Grid iterations on TPU are sequential, so
+read-modify-write accumulation is safe.
 """
 
 from __future__ import annotations
@@ -36,10 +35,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core.expr import AggSpec, Expr, evaluate
+from .grouped_select_agg import (BLOCK_ROWS, LANES, ONE_HOT_COPIES,
+                                 pad_buckets)
 
-LANES = 128
 _NEG = -3.0e38
 _POS = 3.0e38
+
+
+def vmem_bytes(num_join_buckets: int, num_buckets: int, n_aggs: int,
+               n_probe_cols: int, n_build_cols: int,
+               block_rows: int = BLOCK_ROWS) -> int:
+    """Estimated VMEM of one grid step: the one-hot blocks over the join and
+    the group buckets, plus the accumulators, the probe blocks and the
+    per-join-bucket build tables (each padded to the lane width), all
+    double-buffered."""
+    nbj, nbg = pad_buckets(num_join_buckets), pad_buckets(num_buckets)
+    return 4 * LANES * (ONE_HOT_COPIES * (nbj + nbg) * block_rows
+                        + 2 * (n_aggs + 1) * nbg
+                        + 2 * (n_probe_cols + 1) * block_rows
+                        + 2 * (n_build_cols + 1) * nbj)
 
 
 def _kernel(pred: Optional[Expr], aggs: Tuple[AggSpec, ...],
@@ -131,8 +145,8 @@ def grouped_join_agg_p(cols: Tuple[jax.Array, ...], valid: jax.Array,
                        jkey_specs: Tuple[Tuple[str, int, int], ...],
                        gkey_specs: Tuple[Tuple[str, int, int], ...],
                        num_join_buckets: int, num_buckets: int,
-                       block_rows: int = 256,
-                       interpret: bool = True) -> Tuple[jax.Array, ...]:
+                       block_rows: int = BLOCK_ROWS,
+                       interpret: bool) -> Tuple[jax.Array, ...]:
     """cols: tuple of (R, 128) probe arrays; valid: (R, 128) bool;
     present/rtabs: (NBJ_pad, 1) f32 dense build tables.
 
@@ -143,7 +157,7 @@ def grouped_join_agg_p(cols: Tuple[jax.Array, ...], valid: jax.Array,
     assert rows % block_rows == 0, (rows, block_rows)
     nblocks = rows // block_rows
     nbj_pad = present.shape[0]
-    nbg_pad = max(8, num_buckets)
+    nbg_pad = pad_buckets(num_buckets)
 
     in_specs = [
         pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))

@@ -31,6 +31,31 @@ LANES = 128
 _NEG = -3.0e38
 _POS = 3.0e38
 
+BLOCK_ROWS = 256
+#: Mosaic's default scoped-VMEM limit on a TPU v5e; the kernel asks for no more
+VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+#: (buckets, block_rows, lanes) f32 buffers the compiler may hold at once:
+#: the one-hot membership and the masked operands reduced through it.  Fitted
+#: to what Mosaic compiles for a v5e: the worst configuration measured needed
+#: about 2.1 such buffers per bucket (one sum at 60 buckets); 3 leaves margin.
+ONE_HOT_COPIES = 3
+
+
+def pad_buckets(num_buckets: int) -> int:
+    """Bucket rows of the accumulators, padded to whole (8, 128) tiles."""
+    return max(8, -(-num_buckets // 8) * 8)
+
+
+def vmem_bytes(num_buckets: int, n_aggs: int, n_cols: int,
+               block_rows: int = BLOCK_ROWS) -> int:
+    """Estimated VMEM of one grid step: the one-hot block over the buckets,
+    plus the (count + aggregates) accumulators and the input blocks, both
+    double-buffered."""
+    nb = pad_buckets(num_buckets)
+    return 4 * LANES * (ONE_HOT_COPIES * nb * block_rows
+                        + 2 * (n_aggs + 1) * nb
+                        + 2 * (n_cols + 1) * block_rows)
+
 
 def _kernel(pred: Optional[Expr], aggs: Tuple[AggSpec, ...], names: Tuple[str, ...],
             key_specs: Tuple[Tuple[str, int, int], ...], nb: int, *refs):
@@ -88,8 +113,8 @@ def grouped_select_agg_p(cols: Tuple[jax.Array, ...], valid: jax.Array, *,
                          pred: Optional[Expr], aggs: Tuple[AggSpec, ...],
                          names: Tuple[str, ...],
                          key_specs: Tuple[Tuple[str, int, int], ...],
-                         num_buckets: int, block_rows: int = 256,
-                         interpret: bool = True) -> Tuple[jax.Array, ...]:
+                         num_buckets: int, block_rows: int = BLOCK_ROWS,
+                         interpret: bool) -> Tuple[jax.Array, ...]:
     """cols: tuple of (R, 128) arrays; valid: (R, 128) bool.
 
     Returns lane accumulators ``(count, agg_0, ..., agg_k)`` each of shape
@@ -98,7 +123,7 @@ def grouped_select_agg_p(cols: Tuple[jax.Array, ...], valid: jax.Array, *,
     rows = valid.shape[0]
     assert rows % block_rows == 0, (rows, block_rows)
     nblocks = rows // block_rows
-    nb_pad = max(8, num_buckets)
+    nb_pad = pad_buckets(num_buckets)
 
     in_specs = [
         pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))

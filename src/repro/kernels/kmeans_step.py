@@ -49,7 +49,7 @@ def _kernel(x_ref, c_ref, sums_ref, counts_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def kmeans_step_p(x: jax.Array, c: jax.Array, *, block_rows: int = 1024,
-                  interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                  interpret: bool) -> Tuple[jax.Array, jax.Array]:
     """x: (n, d) f32, c: (k, d) f32 → (sums (k, d), counts (k,))."""
     n, d = x.shape
     k = c.shape[0]

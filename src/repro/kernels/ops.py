@@ -1,7 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels (+ jnp fallbacks).
 
 Dispatch policy:
-  * ``mode="pallas"``    — the Pallas kernel (``interpret=True`` on CPU);
+  * ``mode="pallas"``    — the Pallas kernel (``interpret=True`` on CPU only);
   * ``mode="chunked"``   — memory-efficient pure-jnp flash (lax.scan over kv
     blocks + remat): what train/serve steps use so the *compiled* HLO has
     O(S·d) attention footprint — this is the shape the dry-run measures;
@@ -21,11 +21,11 @@ import jax
 import jax.numpy as jnp
 
 from ..core.expr import AggSpec, Expr
+from . import grouped_join_agg as gja
+from . import grouped_select_agg as gsa
 from . import ref
 from .flash_attention import flash_attention_p
 from .fused_select_agg import LANES, fused_select_agg_p
-from .grouped_join_agg import grouped_join_agg_p
-from .grouped_select_agg import grouped_select_agg_p
 from .kmeans_step import kmeans_step_p
 from .segsum import segsum_p
 
@@ -43,7 +43,7 @@ def _pad_rows(a: jax.Array, rows: int) -> jax.Array:
 
 
 def fused_select_agg(table, pred: Expr, aggs: Sequence[AggSpec], *,
-                     block_rows: int = 512, interpret: bool = True) -> Dict[str, jax.Array]:
+                     block_rows: int = 512, interpret: bool) -> Dict[str, jax.Array]:
     """VecTable → Single⟨aggs⟩ via the fused Pallas kernel."""
     names = tuple(sorted(set(pred.fields()) | {f for a in aggs for f in a.expr.fields()}))
     cap = table.capacity
@@ -65,12 +65,29 @@ def fused_select_agg(table, pred: Expr, aggs: Sequence[AggSpec], *,
     return {a.name: out[i] for i, a in enumerate(aggs)}
 
 
+def _select_names(pred: Optional[Expr], keys: Sequence[str],
+                  aggs: Sequence[AggSpec]) -> Tuple[str, ...]:
+    """Columns the grouped select kernel reads, in kernel argument order."""
+    agg_fields = {f for a in aggs for f in a.expr.fields()}
+    pred_fields = set(pred.fields()) if pred is not None else set()
+    return tuple(sorted(pred_fields | agg_fields | set(keys)))
+
+
+def grouped_select_agg_fits(pred: Optional[Expr], keys: Sequence[str],
+                            aggs: Sequence[AggSpec], num_buckets: int) -> bool:
+    """Whether Mosaic compiles the grouped select kernel for this plan at the
+    default block size: its estimated VMEM is within the scoped limit."""
+    n_cols = len(_select_names(pred, keys, aggs))
+    return (gsa.vmem_bytes(num_buckets, len(aggs), n_cols)
+            <= gsa.VMEM_LIMIT_BYTES)
+
+
 def grouped_select_agg(table, pred: Optional[Expr], keys: Sequence[str],
                        aggs: Sequence[AggSpec],
                        max_groups: int,
                        key_domains: Sequence[Tuple[int, int]],
                        num_buckets: int, *,
-                       block_rows: int = 256, interpret: bool = True):
+                       block_rows: int = gsa.BLOCK_ROWS, interpret: bool):
     """VecTable → Vec⟨keys+aggs⟩ via the fused Pallas kernel.
 
     One blockwise pass: fused predicate + dense-bucket accumulation
@@ -80,9 +97,7 @@ def grouped_select_agg(table, pred: Optional[Expr], keys: Sequence[str],
     """
     from ..relational import runtime as rt
 
-    agg_fields = {f for a in aggs for f in a.expr.fields()}
-    pred_fields = set(pred.fields()) if pred is not None else set()
-    names = tuple(sorted(pred_fields | agg_fields | set(keys)))
+    names = _select_names(pred, keys, aggs)
     cap = table.capacity
     rows = -(-cap // LANES)  # ceil
     rows = -(-rows // block_rows) * block_rows
@@ -97,7 +112,7 @@ def grouped_select_agg(table, pred: Optional[Expr], keys: Sequence[str],
     valid = to_lanes(table.valid)
     key_specs = tuple((k, int(lo), int(hi) - int(lo) + 1)
                       for k, (lo, hi) in zip(keys, key_domains))
-    lane_accs = grouped_select_agg_p(
+    lane_accs = gsa.grouped_select_agg_p(
         cols, valid, pred=pred, aggs=tuple(aggs), names=names,
         key_specs=key_specs, num_buckets=num_buckets,
         block_rows=block_rows, interpret=interpret)
@@ -126,6 +141,32 @@ def grouped_select_agg(table, pred: Optional[Expr], keys: Sequence[str],
     return rt.compact(buckets, max_groups)
 
 
+def _join_names(left, right, left_on: Sequence[str], right_on: Sequence[str],
+                keys: Sequence[str], aggs: Sequence[AggSpec],
+                pred: Optional[Expr]) -> Tuple[Tuple[str, ...], Tuple[str, ...]]:
+    """(probe, build) columns the join kernel reads, in argument order."""
+    agg_fields = {f for a in aggs for f in a.expr.fields() if a.fn != "count"}
+    pred_fields = set(pred.fields()) if pred is not None else set()
+    rnames = tuple(sorted((set(keys) | agg_fields)
+                          & (set(right.cols) - set(right_on))))
+    lnames = tuple(sorted((pred_fields | set(left_on)
+                           | ((set(keys) | agg_fields) & set(left.cols)))))
+    return lnames, rnames
+
+
+def grouped_join_agg_fits(left, right, *, left_on: Sequence[str],
+                          right_on: Sequence[str], join_num_buckets: int,
+                          keys: Sequence[str], aggs: Sequence[AggSpec],
+                          num_buckets: int, pred: Optional[Expr]) -> bool:
+    """Whether Mosaic compiles the join kernel for this plan at the default
+    block size: its estimated VMEM is within the scoped limit."""
+    lnames, rnames = _join_names(left, right, left_on, right_on, keys, aggs,
+                                 pred)
+    return (gja.vmem_bytes(join_num_buckets, num_buckets, len(aggs),
+                           len(lnames), len(rnames))
+            <= gsa.VMEM_LIMIT_BYTES)
+
+
 def grouped_join_agg(left, right, *, left_on: Sequence[str],
                      right_on: Sequence[str],
                      join_key_domains: Sequence[Tuple[int, int]],
@@ -133,7 +174,7 @@ def grouped_join_agg(left, right, *, left_on: Sequence[str],
                      aggs: Sequence[AggSpec], max_groups: int,
                      key_domains: Sequence[Tuple[int, int]],
                      num_buckets: int, pred: Optional[Expr] = None,
-                     block_rows: int = 256, interpret: bool = True):
+                     block_rows: int = gsa.BLOCK_ROWS, interpret: bool):
     """(probe VecTable, build VecTable) → Vec⟨keys+aggs⟩, one fused kernel.
 
     The whole select→join→group pipeline (``vec.FusedJoinGroupAgg`` under
@@ -149,12 +190,8 @@ def grouped_join_agg(left, right, *, left_on: Sequence[str],
 
     keys = tuple(keys)
     aggs = tuple(aggs)
-    agg_fields = {f for a in aggs for f in a.expr.fields() if a.fn != "count"}
-    pred_fields = set(pred.fields()) if pred is not None else set()
-    rnames = tuple(sorted((set(keys) | agg_fields)
-                          & (set(right.cols) - set(right_on))))
-    lnames = tuple(sorted((pred_fields | set(left_on)
-                           | ((set(keys) | agg_fields) & set(left.cols)))))
+    lnames, rnames = _join_names(left, right, left_on, right_on, keys, aggs,
+                                 pred)
 
     cap = left.capacity
     rows = -(-cap // LANES)  # ceil
@@ -171,7 +208,7 @@ def grouped_join_agg(left, right, *, left_on: Sequence[str],
 
     # dense build tables over the join-bucket axis (first occurrence wins)
     nbj = int(join_num_buckets)
-    nbj_pad = max(8, nbj)
+    nbj_pad = gsa.pad_buckets(nbj)
     cap_r = right.capacity
     rbid, rok = rt._bucket_ids_checked(right, right_on, join_key_domains)
     slot = jnp.where(rok & right.valid, rbid, nbj)
@@ -192,7 +229,7 @@ def grouped_join_agg(left, right, *, left_on: Sequence[str],
                        for k, (lo, hi) in zip(left_on, join_key_domains))
     gkey_specs = tuple((k, int(lo), int(hi) - int(lo) + 1)
                        for k, (lo, hi) in zip(keys, key_domains))
-    lane_accs = grouped_join_agg_p(
+    lane_accs = gja.grouped_join_agg_p(
         cols, valid, present, rtabs, pred=pred, aggs=aggs,
         lnames=lnames, rnames=rnames, jkey_specs=jkey_specs,
         gkey_specs=gkey_specs, num_join_buckets=nbj, num_buckets=num_buckets,
@@ -223,7 +260,7 @@ def grouped_join_agg(left, right, *, left_on: Sequence[str],
 
 
 def segsum(data: jax.Array, seg_ids: jax.Array, num_segments: int, *,
-           block_rows: int = 512, interpret: bool = True) -> jax.Array:
+           block_rows: int = 512, interpret: bool) -> jax.Array:
     n, d = data.shape
     rows = -(-n // block_rows) * block_rows
     data_p = _pad_rows(data.astype(jnp.float32), rows)
@@ -237,7 +274,7 @@ def segsum(data: jax.Array, seg_ids: jax.Array, num_segments: int, *,
 
 
 def kmeans_step(x: jax.Array, c: jax.Array, *, block_rows: int = 1024,
-                interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                interpret: bool) -> Tuple[jax.Array, jax.Array]:
     n, d = x.shape
     rows = -(-n // block_rows) * block_rows
     if rows != n:
@@ -331,7 +368,7 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
               sm_scale: Optional[float] = None, mode: str = "chunked",
-              interpret: bool = True, unroll: bool = False) -> jax.Array:
+              interpret: bool = False, unroll: bool = False) -> jax.Array:
     if mode == "pallas":
         return flash_attention_p(q, k, v, causal=causal, window=window,
                                  sm_scale=sm_scale, interpret=interpret)

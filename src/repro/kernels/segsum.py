@@ -38,7 +38,7 @@ def _kernel(k_total: int, x_ref, seg_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "block_rows", "interpret"))
 def segsum_p(data: jax.Array, seg_ids: jax.Array, *, num_segments: int,
-             block_rows: int = 512, interpret: bool = True) -> jax.Array:
+             block_rows: int = 512, interpret: bool) -> jax.Array:
     """data: (n, d) f32; seg_ids: (n,) i32 in [0, num_segments). → (K, d)."""
     n, d = data.shape
     assert n % block_rows == 0, (n, block_rows)

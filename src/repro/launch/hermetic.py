@@ -1,10 +1,11 @@
-"""Deterministic environments for jax-spawning subprocesses.
+"""Deterministic environments for jax-spawning subprocesses — CPU only.
 
-Tests and benchmarks launch workers with a minimal env so XLA flags (device
-counts must be set before jax initializes) and stray user configuration
-can't leak in.  Centralised here because every spawn needs the same
-footgun-guard: containers that ship libtpu but have no TPU attached hang
-for minutes in TPU init unless the platform is pinned.
+For test and CPU-benchmark subprocesses only: tests and the CPU benchmarks
+launch workers with a minimal env so XLA flags (device counts must be set
+before jax initializes) and stray user configuration can't leak in, and the
+platform defaults to ``JAX_PLATFORMS=cpu``.  Nothing on the chip path spawns a
+JAX child: a chip belongs to one process, and a parent that has touched JAX
+holds it (``chip_smoke.py`` runs everything in one process).
 """
 
 from __future__ import annotations

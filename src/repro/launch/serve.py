@@ -510,8 +510,10 @@ def main(argv=None):
     import jax.numpy as jnp
 
     from ..configs import get_config, get_reduced
+    from .cache import enable_compile_cache
     from ..models.api import build_model, make_serve_step
 
+    enable_compile_cache()
     previous_tracer = None
     if args.trace:
         previous_tracer = set_tracer(Tracer(enabled=True))

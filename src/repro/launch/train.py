@@ -27,6 +27,7 @@ from ..distributed.checkpoint import CheckpointManager
 from ..distributed.fault import StepRunner
 from ..models.api import build_model, make_train_step
 from ..train.optimizer import AdamW
+from .cache import enable_compile_cache
 
 
 def make_batch_fn(cfg, pipeline: TokenPipeline):
@@ -68,6 +69,7 @@ def main(argv=None):
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = build_model(cfg)
     print(f"[train] {cfg.arch}: {cfg.n_params()/1e6:.1f}M params "

@@ -13,7 +13,7 @@ the paper's three experiments.
 from __future__ import annotations
 
 from datetime import date
-from typing import Callable, Dict
+from typing import Callable, Dict, Tuple
 
 import numpy as np
 
@@ -353,3 +353,30 @@ def ref_q19(t):
 REFERENCES: Dict[str, Callable] = {
     "q1": ref_q1, "q4": ref_q4, "q6": ref_q6, "q12": ref_q12, "q14": ref_q14, "q19": ref_q19,
 }
+
+#: group keys of the grouped queries: result rows are compared in key order
+GROUP_KEYS: Dict[str, Tuple[str, ...]] = {
+    "q1": ("l_returnflag", "l_linestatus"),
+    "q4": ("o_orderpriority",),
+    "q12": ("l_shipmode",),
+}
+
+
+def _sort_rows(d, keys):
+    order = np.lexsort([np.asarray(d[k]) for k in reversed(keys)])
+    return {k: np.asarray(v)[order] for k, v in d.items()}
+
+
+def assert_result_close(got, want, keys=()) -> None:
+    """Compare a query result with its reference: integer columns exactly,
+    float columns to ``rtol=2e-4``, grouped rows in ``keys`` order."""
+    if keys:
+        got, want = _sort_rows(got, keys), _sort_rows(want, keys)
+    assert set(want) <= set(got), f"missing columns: {set(want) - set(got)}"
+    for k in want:
+        g, w = np.asarray(got[k]), np.asarray(want[k])
+        assert g.shape == w.shape, f"{k}: shape {g.shape} vs {w.shape}"
+        if np.issubdtype(w.dtype, np.integer):
+            np.testing.assert_array_equal(g.astype(np.int64), w.astype(np.int64), err_msg=k)
+        else:
+            np.testing.assert_allclose(g.astype(np.float64), w, rtol=2e-4, err_msg=k)

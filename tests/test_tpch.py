@@ -23,31 +23,6 @@ def ctx(tables):
     return tpch.make_context(tables, pad_to=256)
 
 
-def _sort_rows(d, keys):
-    order = np.lexsort([np.asarray(d[k]) for k in reversed(keys)])
-    return {k: np.asarray(v)[order] for k, v in d.items()}
-
-
-def _assert_result_close(got, want, keys=()):
-    if keys:
-        got, want = _sort_rows(got, keys), _sort_rows(want, keys)
-    assert set(want) <= set(got), f"missing columns: {set(want) - set(got)}"
-    for k in want:
-        g, w = np.asarray(got[k]), np.asarray(want[k])
-        assert g.shape == w.shape, f"{k}: shape {g.shape} vs {w.shape}"
-        if np.issubdtype(w.dtype, np.integer):
-            np.testing.assert_array_equal(g.astype(np.int64), w.astype(np.int64), err_msg=k)
-        else:
-            np.testing.assert_allclose(g.astype(np.float64), w, rtol=2e-4, err_msg=k)
-
-
-GROUP_KEYS = {
-    "q1": ("l_returnflag", "l_linestatus"),
-    "q4": ("o_orderpriority",),
-    "q12": ("l_shipmode",),
-}
-
-
 @pytest.mark.parametrize("qname", sorted(tpch.QUERIES))
 class TestTPCH:
     def test_interpreter_matches_reference(self, qname, ctx, tables):
@@ -58,17 +33,17 @@ class TestTPCH:
         got = out if isinstance(out, dict) else {"result": out}
         # interpreter returns exact tables; scalars come back as dicts
         got = {k: np.asarray(v) for k, v in got.items()}
-        _assert_result_close(got, want, GROUP_KEYS.get(qname, ()))
+        tpch.assert_result_close(got, want, tpch.GROUP_KEYS.get(qname, ()))
 
     def test_compiled_sequential(self, qname, ctx, tables):
         got = tpch.QUERIES[qname](ctx).collect()
         want = tpch.REFERENCES[qname](tables)
-        _assert_result_close(got, want, GROUP_KEYS.get(qname, ()))
+        tpch.assert_result_close(got, want, tpch.GROUP_KEYS.get(qname, ()))
 
     def test_compiled_parallel(self, qname, ctx, tables):
         got = tpch.QUERIES[qname](ctx).collect(parallel=4)
         want = tpch.REFERENCES[qname](tables)
-        _assert_result_close(got, want, GROUP_KEYS.get(qname, ()))
+        tpch.assert_result_close(got, want, tpch.GROUP_KEYS.get(qname, ()))
 
 
 def test_parallel_rewrite_actually_fires_on_q6(ctx):

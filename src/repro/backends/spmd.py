@@ -240,15 +240,34 @@ class SpmdCompiled:
     program: Program
     fn: Callable[..., List[Any]]
     traced_fn: Optional[Callable[..., Any]] = None
+    mesh: Optional[Mesh] = None
+    axis: str = "workers"
+
+    def place(self, sources: Any) -> Dict[str, Any]:
+        """Put the sources where the mesh program reads them: every leaf
+        whose leading dim splits evenly over the worker axis is sharded
+        along it (each device holds its slice of the rows), the rest is
+        replicated.  Host (numpy) leaves go straight to their devices;
+        leaves already placed so are returned as they are."""
+        n = self.mesh.shape[self.axis]
+        split = NamedSharding(self.mesh, P(self.axis))
+        whole = NamedSharding(self.mesh, P())
+
+        def put(x):
+            shape = jnp.shape(x)
+            return jax.device_put(
+                x, split if shape and shape[0] % n == 0 else whole)
+
+        return jax.tree_util.tree_map(put, dict(sources or {}))
 
     def __call__(self, sources=None, *args):
-        return self.fn(dict(sources or {}), *args)
+        return self.fn(self.place(sources), *args)
 
     def run_traced(self, sources=None, *args):
         """Execute and measure: ``(results, {tap key → TapRecord}, {})``."""
         from ..obs.feedback import TapRecord
 
-        outs, taps = self.traced_fn(dict(sources or {}), *args)
+        outs, taps = self.traced_fn(self.place(sources), *args)
         cards = {
             k: TapRecord(int(occ), None if ri is None else int(ri), int(ro))
             for k, (occ, ri, ro) in taps.items()
@@ -296,4 +315,4 @@ class SpmdBackend:
 
         fn = jax.jit(run) if self.jit else run
         tfn = jax.jit(run_traced) if self.jit else run_traced
-        return SpmdCompiled(program, fn, tfn)
+        return SpmdCompiled(program, fn, tfn, self.mesh, self.axis)

@@ -332,19 +332,25 @@ class Context:
                       if a.dtype.kind in ("U", "S") else a)
         return out
 
-    def sources(self, sharding: Any = None) -> Dict[str, Any]:
-        """Every registered table as a device-resident VecTable.
+    def sources(self, plan: Any = None) -> Dict[str, Any]:
+        """Every registered table as a device-resident VecTable, placed
+        where ``plan`` (a compiled plan) reads its inputs.
 
-        ``sharding`` places each column as it is built: for the spmd target,
-        ``NamedSharding(mesh, P(axis))`` gives each device its slice of the
-        rows instead of staging whole tables on the first device."""
+        A plan whose executable places its own inputs (the spmd target
+        shards rows over its mesh) gets the tables straight from the host,
+        each device only its slice; any other plan, or none, gets them on
+        the default device."""
+        import jax
+
         from ..relational.runtime import VecTable
 
-        return {
-            name: VecTable.from_numpy(self._physical_columns(name),
-                                      self.capacity(name), sharding)
-            for name, data in self.tables.items()
-        }
+        place = getattr(getattr(plan, "executable", None), "place",
+                        jax.device_put)
+        return place({
+            name: VecTable.padded_host(self._physical_columns(name),
+                                       self.capacity(name))
+            for name in self.tables
+        })
 
     def execute(self, frame: Frame, parallel: Optional[int] = None,
                 use_kernels: bool = False, backend: Any = None,
@@ -360,7 +366,7 @@ class Context:
                                 stream_table=stream_table,
                                 batch_rows=batch_rows)
         src = (self.tables if get_target(target).source_kind == "numpy"
-               else self.sources())
+               else self.sources(compiled))
         (out,) = compiled(src)
         return self._decode_output(frame, _to_numpy(out))
 

@@ -368,8 +368,13 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
               sm_scale: Optional[float] = None, mode: str = "chunked",
-              interpret: bool = False, unroll: bool = False) -> jax.Array:
+              interpret: Optional[bool] = None,
+              unroll: bool = False) -> jax.Array:
     if mode == "pallas":
+        if interpret is None:  # where the caller runs: the default backend
+            from . import interpret_on
+
+            interpret = interpret_on(jax.default_backend())
         return flash_attention_p(q, k, v, causal=causal, window=window,
                                  sm_scale=sm_scale, interpret=interpret)
     if mode == "chunked":

@@ -45,10 +45,15 @@ class VecTable:
         return jnp.sum(self.valid.astype(jnp.int32))
 
     @staticmethod
-    def from_numpy(data: Mapping[str, np.ndarray], capacity: Optional[int] = None,
-                   sharding: Optional[jax.sharding.Sharding] = None) -> "VecTable":
-        """Pad host columns to ``capacity`` and put them on the device —
-        placed by ``sharding`` when given, else on the default device."""
+    def from_numpy(data: Mapping[str, np.ndarray], capacity: Optional[int] = None) -> "VecTable":
+        """Pad host columns to ``capacity`` and put them on the default device."""
+        return jax.device_put(VecTable.padded_host(data, capacity))
+
+    @staticmethod
+    def padded_host(data: Mapping[str, np.ndarray],
+                    capacity: Optional[int] = None) -> "VecTable":
+        """Host columns padded to ``capacity``, still numpy: a backend that
+        places its inputs itself puts them on its devices from here."""
         n = len(next(iter(data.values())))
         cap = capacity or n
         if cap < n:
@@ -57,9 +62,8 @@ class VecTable:
         for k, v in data.items():
             v = np.asarray(v)
             pad = np.zeros((cap - n,) + v.shape[1:], dtype=v.dtype)
-            cols[k] = jax.device_put(np.concatenate([v, pad]), sharding)
-        valid = jax.device_put(np.arange(cap) < n, sharding)
-        return VecTable(cols, valid)
+            cols[k] = np.concatenate([v, pad])
+        return VecTable(cols, np.arange(cap) < n)
 
     def to_numpy(self) -> Dict[str, np.ndarray]:
         mask = np.asarray(self.valid)

@@ -47,6 +47,16 @@ SPMD_SCRIPT = textwrap.dedent("""
     assert len(records) == 6 and {r["phase"] for r in records} == {"spmd"}
     bad = [r for r in records if not r["ok"]]
     assert not bad, bad
+    # the spmd target places the tables itself: each device holds a
+    # quarter of every table's rows, none holds a whole table
+    from repro.relational import tpch
+    ctx = tpch.make_context(tpch.generate(sf=0.002, seed=0))
+    res = ctx.compile(tpch.QUERIES["q6"](ctx), target="spmd", parallel=4)
+    for name, vt in ctx.sources(res).items():
+        for a in jax.tree.leaves(vt):
+            shards = a.addressable_shards
+            assert len({s.device for s in shards}) == 4, name
+            assert all(s.data.shape[0] * 4 == a.shape[0] for s in shards), name
     print("SPMD_OK")
 """)
 

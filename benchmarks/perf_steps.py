@@ -22,10 +22,7 @@ driver's decision), and markdown tables on stdout.
 Usage: PYTHONPATH=src:. python benchmarks/perf_steps.py [--compile-only]
 (--compile-only runs just the compile-pass/cost report — the artifact CI
 uploads per PR; --groupby-bench runs just the BENCH_5.json group-by
-strategy benchmark; --trace runs traced executions of the same cells →
-artifacts/perf_steps/trace__<cell>.json Chrome traces + BENCH_6.json with
-the per-op runtime breakdown, cardinality-miss stats, and the <5%
-tracing-disabled overhead guard; --robust-bench measures the guarded
+strategy benchmark; --robust-bench measures the guarded
 compile/execute path with no faults armed vs guard=False → BENCH_7.json
 with its own <5% overhead guard plus the fault-recovery wall time;
 --join-bench runs the BENCH_8.json join-strategy benchmark: sorted vs
@@ -146,7 +143,7 @@ def compile_pass_report():
 
 def _groupby_cells():
     """The two grouped-aggregation cells shared by the BENCH_5 strategy
-    benchmark and the BENCH_6 traced-execution report: a TPC-H Q1-style
+    benchmark and the BENCH_7 robustness benchmark: a TPC-H Q1-style
     low-NDV grouping (two small-domain keys, selective filter) and a
     high-NDV grouping whose key domain (2^20) ≫ rows (2^13)."""
     import numpy as np
@@ -743,84 +740,6 @@ def stream_bench_report(reps: int = 7):
             and recovery_s < 60.0 and oracle_ok)
 
 
-def trace_report(reps: int = 30):
-    """Traced executions → Chrome traces + BENCH_6.json.
-
-    Per cell: a ``trace__<cell>.json`` Chrome trace (compile-pass spans
-    nested under the compile span, the execute span, per-operator
-    cardinality annotations), the jit path's estimate-vs-actual cardinality
-    records, and the eager interpreter's per-operator wall-time breakdown.
-    Plus the overhead guard: with tracing *disabled*, the instrumented
-    ``CompileResult.__call__`` on the low-NDV Q1-style hot path must stay
-    within 5% of calling the bare executable (the BENCH_5 measurement
-    convention).
-    """
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import statistics
-    import jax
-    from repro.compiler import PlanCache
-    from repro.obs import tracing, write_chrome_trace
-
-    ctx, cells = _groupby_cells()
-    sources = ctx.sources()
-    record = {"bench": "traced_execution", "reps": reps}
-
-    for cell, (rows, q) in cells.items():
-        with tracing() as tr:
-            res = ctx.compile(q, optimize="cost", cache=PlanCache())
-            jax.block_until_ready(res(sources))
-        trace_path = OUT / f"trace__{cell}.json"
-        write_chrome_trace(trace_path, tr)
-        prof = res.profile
-        entry = {
-            "rows": rows,
-            "strategy": dict(res.strategy),
-            "wall_s": prof.wall_s,
-            "worst_cardinality_miss": prof.worst_miss,
-            "operators": prof.records(),
-        }
-        # the eager oracle can time individual operators — the per-op
-        # runtime breakdown the jitted path cannot observe from inside XLA
-        with tracing():
-            ires = ctx.compile(q, target="interp", cache=PlanCache())
-            ires(ctx.tables)
-        entry["interp_op_wall_s"] = {o["op"]: o["wall_s"]
-                                     for o in ires.profile.records()}
-        record[cell] = entry
-        print(f"[perf] trace {cell}: {prof.wall_s * 1e3:.1f} ms, "
-              f"worst miss {prof.worst_miss * 100:.0f}%, "
-              f"{len(prof.observations)} op(s) → {trace_path.name}", flush=True)
-
-    # overhead guard: tracing disabled, wrapped call vs bare executable
-    q = cells["low_ndv_q1"][1]
-    res = ctx.compile(q, cache=PlanCache())
-    jax.block_until_ready(res(sources))  # warm
-
-    def median_call(fn):
-        ts = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            ts.append(time.perf_counter() - t0)
-        return statistics.median(ts)
-
-    direct_s = median_call(lambda: res.executable(sources))
-    wrapped_s = median_call(lambda: res(sources))
-    ratio = wrapped_s / direct_s
-    ok = ratio < 1.05
-    record["overhead_guard"] = {
-        "cell": "low_ndv_q1", "direct_us": direct_s * 1e6,
-        "wrapped_us": wrapped_s * 1e6, "ratio": ratio,
-        "threshold": 1.05, "pass": ok,
-    }
-    print(f"[perf] tracing-disabled overhead: direct {direct_s * 1e6:.0f} us, "
-          f"wrapped {wrapped_s * 1e6:.0f} us → ratio {ratio:.3f} "
-          f"({'PASS' if ok else 'FAIL'} < 1.05)", flush=True)
-
-    (ROOT / "BENCH_6.json").write_text(json.dumps(record, indent=2))
-    print(f"[perf] wrote {ROOT / 'BENCH_6.json'}")
-
-
 def robust_bench_report(reps: int = 30):
     """Guarded-execution overhead with no faults armed → BENCH_7.json.
 
@@ -907,9 +826,6 @@ def main():
     if "--robust-bench" in sys.argv:
         if not robust_bench_report():
             sys.exit(1)
-        return
-    if "--trace" in sys.argv:
-        trace_report()
         return
     if "--groupby-bench" in sys.argv:
         groupby_bench_report()

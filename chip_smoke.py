@@ -18,8 +18,9 @@ its slice of the rows.
 
 Each query prints one JSON line: ``plan_s`` (``Context.compile``),
 ``batch_compile_s`` (the XLA compile of the whole phase, all plans at once),
-``first_traced_s`` (the guarded, traced first call), ``first_plain_s`` and
-``warm_s`` (untraced calls, as users run them), the ``tpu_custom_call``
+``first_traced_s`` (the guarded first call, under the tracer without its
+cardinality taps, so on the executable users run), ``first_plain_s`` and
+``warm_s`` (untraced calls), the ``tpu_custom_call``
 count, ``degraded``, and each device's peak bytes since start.  The last
 line is ``{"ok": true, "device": {...}}``.  Exits non-zero without that line
 when JAX finds no TPU or any query fails.
@@ -42,8 +43,8 @@ ROOT = Path(__file__).resolve().parent
 KERNEL_QUERIES = ("q1", "q6")
 KERNEL = 'custom_call_target="tpu_custom_call"'
 WARM_RUNS = 2
-#: XLA compiles run at once (each phase has twelve: six plans, two variants)
-COMPILE_THREADS = 12
+#: XLA compiles run at once (each phase has six plans)
+COMPILE_THREADS = 6
 
 
 def fail(msg: str) -> int:
@@ -83,28 +84,26 @@ def compile_plans(ctx, phase: str, **compile_kw):
 
 
 def xla_compile_all(plans, sources) -> dict:
-    """XLA-compile both jitted variants of every plan at once: the traced
-    one, which the first (guarded, traced) call runs, and the plain one,
-    which every untraced call runs.  A sort over 60M rows takes minutes to
-    compile for a TPU and compiles release the GIL; the calls then reuse
-    these executables.  Returns, per plan, the futures of the two compiled
-    HLO texts (traced, plain); returns when all have finished."""
+    """XLA-compile every plan's jitted function at once, the one every call
+    runs.  A sort over 60M rows takes minutes to compile for a TPU and
+    compiles release the GIL; the calls then reuse these executables.
+    Returns, per plan, the future of its compiled HLO text; returns when all
+    have finished."""
     def compile_one(fn) -> str:
         return fn.lower(dict(sources)).compile().as_text()
 
     with ThreadPoolExecutor(COMPILE_THREADS) as pool:
-        return {q: (pool.submit(compile_one, res.executable.traced_fn),
-                    pool.submit(compile_one, res.executable.fn))
+        return {q: pool.submit(compile_one, res.executable.fn)
                 for q, res in plans.items()}
 
 
 def run_phase(sources, wants, recs, plans, devices) -> list:
     """Run every compiled plan of one phase; fills in and prints ``recs``.
 
-    The first call of each plan runs under the tracer with the guard armed,
-    as users get it: that is where ``degraded`` and ``robust.fallback.step``
-    are read.  The timed calls then run with tracing off, through the plain
-    executable users run."""
+    The first call of each plan runs with the guard armed, as users get it,
+    under the tracer without its cardinality taps (so on the executable
+    users run): that is where ``degraded`` and ``robust.fallback.step`` are
+    read.  The timed calls then run with tracing off."""
     import jax
 
     from repro.frontends.dataflow import _to_numpy
@@ -120,9 +119,8 @@ def run_phase(sources, wants, recs, plans, devices) -> list:
                 continue
             res, keys = plans[qname], tpch.GROUP_KEYS.get(qname, ())
             rec["batch_compile_s"] = batch_s
-            _, plain_hlo = (f.result() for f in compiled[qname])
-            rec["tpu_custom_calls"] = plain_hlo.count(KERNEL)
-            with tracing() as tracer:
+            rec["tpu_custom_calls"] = compiled[qname].result().count(KERNEL)
+            with tracing(cardinalities=False) as tracer:
                 t0 = time.perf_counter()
                 outs = jax.block_until_ready(res(sources))
                 rec["first_traced_s"] = time.perf_counter() - t0

@@ -94,6 +94,15 @@ def record_tap(ctx: EvalCtx, program: Program, index: int, ins: Instruction,
         entry[2] = entry[2] + rows_out
 
 
+def op_scope(index: int, ins: Instruction):
+    """The named scope an instruction's ops are traced under,
+    ``<index>.<opcode>`` (``007.vec.MergeJoinSorted``): it becomes part of
+    each HLO op's ``op_name`` metadata, so a device profile can be read per
+    operator.  Nested bodies nest their own scopes.  Trace-time only: the
+    compiled ops are the same with or without it."""
+    return jax.named_scope(f"{index:03d}.{ins.opcode}")
+
+
 def evaluate_program(ctx: EvalCtx, program: Program, *args: Any) -> List[Any]:
     """Trace a CVM program into JAX ops (call under jit)."""
     if len(args) != len(program.inputs):
@@ -104,7 +113,8 @@ def evaluate_program(ctx: EvalCtx, program: Program, *args: Any) -> List[Any]:
         if fn is None:
             raise NotImplementedError(f"no JAX emitter for {ins.opcode}")
         ins_args = [env[r.name] for r in ins.inputs]
-        outs = fn(ctx, ins, ins_args)
+        with op_scope(i, ins):
+            outs = fn(ctx, ins, ins_args)
         if ctx.taps is not None:
             record_tap(ctx, program, i, ins, ins_args, outs)
         for r, v in zip(ins.outputs, outs):

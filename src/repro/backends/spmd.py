@@ -219,7 +219,8 @@ def evaluate_spmd_program(ctx: EvalCtx, program: Program, *args: Any) -> List[An
         if fn is None:
             raise NotImplementedError(f"spmd backend: no emitter for {ins.opcode}")
         ins_args = [env[r.name] for r in ins.inputs]
-        outs = fn(ctx, ins, ins_args)
+        with base_emit.op_scope(i, ins):
+            outs = fn(ctx, ins, ins_args)
         if ctx.taps is not None:
             # top-level only: MeshExecute bodies run under shard_map with a
             # fresh tap-free ctx, so a stacked MeshExecute output is tapped

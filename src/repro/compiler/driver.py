@@ -170,10 +170,15 @@ class CompileResult:
         maybe_inject("backend.execute", target=self.target,
                      program=self.source.name)
         tracer = get_tracer()
-        runner = getattr(self.executable, "run_traced", None)
-        if not tracer.enabled or runner is None:
+        if not tracer.enabled:
             # the hot path: plain dispatch, no span, no profile bookkeeping
             return self.executable(sources, *args)
+        runner = getattr(self.executable, "run_traced", None)
+        if runner is None or not tracer.cardinalities:
+            with tracer.span(f"execute:{self.source.name}", cat="execute",
+                             target=self.target,
+                             fingerprint=self.fingerprint[:12]):
+                return self.executable(sources, *args)
 
         from ..obs import feedback as fb
 

@@ -18,6 +18,7 @@ import numpy as np
 from ..core import Builder, Program, verify
 from ..core.expr import AggSpec, Col, Expr, col, const
 from ..core.types import BAG, Atom, Bag, CollectionType, TupleType
+from ..obs.trace import get_tracer
 
 _ids = itertools.count()
 
@@ -290,29 +291,32 @@ class Context:
         every target's declarative lowering path (and the plan cache)."""
         from ..compiler import compile as cvm_compile
 
-        return cvm_compile(
-            frame.program(),
-            target=target,
-            parallel=parallel,
-            # statistics feed both the costed search and forced physical
-            # strategies (a forced groupby=direct needs key-domain bounds);
-            # string tables always need them — the vec lowering remaps
-            # string-literal predicates through the global dictionary
-            catalog=self.catalog(
-                with_stats=optimize is not None or strategy is not None
-                or self._has_strings()),
-            use_kernels=use_kernels,
-            fuse=fuse,
-            backend=backend,
-            cache=cache,
-            optimize=optimize,
-            strategy=strategy,
-            store=store,
-            memory_budget=memory_budget,
-            guard=guard,
-            stream_table=stream_table,
-            batch_rows=batch_rows,
-        )
+        with get_tracer().span("frontend.compile", cat="frontend",
+                               target=target):
+            return cvm_compile(
+                frame.program(),
+                target=target,
+                parallel=parallel,
+                # statistics feed both the costed search and forced physical
+                # strategies (a forced groupby=direct needs key-domain
+                # bounds); string tables always need them — the vec lowering
+                # remaps string-literal predicates through the global
+                # dictionary
+                catalog=self.catalog(
+                    with_stats=optimize is not None or strategy is not None
+                    or self._has_strings()),
+                use_kernels=use_kernels,
+                fuse=fuse,
+                backend=backend,
+                cache=cache,
+                optimize=optimize,
+                strategy=strategy,
+                store=store,
+                memory_budget=memory_budget,
+                guard=guard,
+                stream_table=stream_table,
+                batch_rows=batch_rows,
+            )
 
     def _physical_columns(self, name: str) -> Dict[str, np.ndarray]:
         """Columns in their physical dtypes: string columns become i32
@@ -339,18 +343,29 @@ class Context:
         A plan whose executable places its own inputs (the spmd target
         shards rows over its mesh) gets the tables straight from the host,
         each device only its slice; any other plan, or none, gets them on
-        the default device."""
+        the default device.
+
+        Traced as ``sources`` (``tables``, host ``bytes``), with the host
+        padding and string coding in ``sources.pad`` and the hand-off to
+        the devices in ``sources.place``."""
         import jax
 
         from ..relational.runtime import VecTable
 
         place = getattr(getattr(plan, "executable", None), "place",
                         jax.device_put)
-        return place({
-            name: VecTable.padded_host(self._physical_columns(name),
-                                       self.capacity(name))
-            for name in self.tables
-        })
+        tracer = get_tracer()
+        with tracer.span("sources", cat="sources",
+                         tables=len(self.tables)) as sp:
+            with tracer.span("sources.pad", cat="sources"):
+                host = {
+                    name: VecTable.padded_host(self._physical_columns(name),
+                                               self.capacity(name))
+                    for name in self.tables
+                }
+            sp.set(bytes=sum(a.nbytes for a in jax.tree.leaves(host)))
+            with tracer.span("sources.place", cat="sources"):
+                return place(host)
 
     def execute(self, frame: Frame, parallel: Optional[int] = None,
                 use_kernels: bool = False, backend: Any = None,
@@ -410,10 +425,10 @@ def _infer_atom(v: np.ndarray) -> Atom:
 
 
 def _to_numpy(out: Any) -> Dict[str, np.ndarray]:
-    from ..relational.runtime import VecTable
+    from ..relational.runtime import VecTable, fetch
 
     if isinstance(out, VecTable):
         return out.to_numpy()
     if isinstance(out, dict):
-        return {k: np.asarray(v) for k, v in out.items()}
-    return {"result": np.asarray(out)}
+        return fetch(out, lambda d: {k: np.asarray(v) for k, v in d.items()})
+    return fetch(out, lambda a: {"result": np.asarray(a)})

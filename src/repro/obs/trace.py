@@ -18,10 +18,13 @@ measurement substrate for both sides:
 Spans are pure host-side bookkeeping: jitted bodies are never instrumented
 from inside (no host callbacks) — backends record spans around ``jit``
 boundaries and report per-operator cardinalities via returned scalars (see
-``repro.obs.feedback``).
+``repro.obs.feedback``).  An enabled span is also mirrored onto the JAX
+profiler's host timeline as a ``TraceAnnotation`` named ``cvm/<span name>``,
+so a profiler trace shows the program's own spans on the same clock as the
+device's operations.
 
 This module depends only on the standard library — importing it never pulls
-in jax.
+in jax; the profiler mirror imports it on the first enabled span.
 """
 
 from __future__ import annotations
@@ -59,12 +62,33 @@ class DegradedWarning(ObsWarning):
 
 _ids = itertools.count(1)
 
+#: ``jax.profiler.TraceAnnotation`` once imported; ``False`` where jax
+#: cannot be imported (the mirror is then skipped)
+_ANNOTATION: Any = None
+
+#: prefix of a span's mirror on the profiler's host timeline
+PROFILER_PREFIX = "cvm/"
+
+
+def _annotation() -> Any:
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation as _ANNOTATION
+        except ImportError:
+            _ANNOTATION = False
+    return _ANNOTATION
+
 
 class Span:
-    """One timed interval with typed attributes; records itself on exit."""
+    """One timed interval with typed attributes; records itself on exit.
+
+    Entered, it also opens a ``cvm/<name>`` profiler annotation for its
+    interval (an interval recorded with :meth:`Tracer.record_complete` is
+    not mirrored)."""
 
     __slots__ = ("tracer", "name", "cat", "args", "span_id", "parent_id",
-                 "tid", "t0", "dur_s")
+                 "tid", "t0", "dur_s", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]) -> None:
@@ -77,6 +101,7 @@ class Span:
         self.tid = threading.get_ident()
         self.t0 = 0.0
         self.dur_s = 0.0
+        self._mirror: Any = None
 
     def set(self, **attrs: Any) -> "Span":
         """Attach attributes mid-span (e.g. results known only at the end)."""
@@ -87,11 +112,18 @@ class Span:
         stack = self.tracer._stack()
         self.parent_id = stack[-1].span_id if stack else None
         stack.append(self)
+        annotation = _annotation()
+        if annotation:
+            self._mirror = annotation(PROFILER_PREFIX + self.name)
+            self._mirror.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         self.dur_s = time.perf_counter() - self.t0
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
+            self._mirror = None
         stack = self.tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -121,11 +153,18 @@ _MAX_HIST_SAMPLES = 65_536
 
 
 class Tracer:
-    """Collects spans, counters, histograms, and events for one workload."""
+    """Collects spans, counters, histograms, and events for one workload.
 
-    def __init__(self, enabled: bool = True, max_events: int = 100_000) -> None:
+    ``cardinalities`` (default on) has compiled plans run their traced twin,
+    which returns per-operator row counts (``CompileResult.profile``); off,
+    plans run the executable users run, inside an ``execute:`` span, and
+    spans, counters and events still record."""
+
+    def __init__(self, enabled: bool = True, max_events: int = 100_000,
+                 cardinalities: bool = True) -> None:
         self.enabled = enabled
         self.max_events = max_events
+        self.cardinalities = cardinalities
         self.epoch = time.perf_counter()      # span timestamps are relative
         self.epoch_wall = time.time()
         self.spans: List[Span] = []
@@ -272,10 +311,14 @@ class _TracingContext:
         return False
 
 
-def tracing(enabled: bool = True, max_events: int = 100_000) -> _TracingContext:
+def tracing(enabled: bool = True, max_events: int = 100_000,
+            cardinalities: bool = True) -> _TracingContext:
     """``with tracing() as tracer: ...`` — installs (and restores) the
-    process-global tracer around one traced workload."""
-    return _TracingContext(Tracer(enabled=enabled, max_events=max_events))
+    process-global tracer around one traced workload.
+    ``cardinalities=False`` keeps plans on the executable users run (see
+    :class:`Tracer`)."""
+    return _TracingContext(Tracer(enabled=enabled, max_events=max_events,
+                                  cardinalities=cardinalities))
 
 
 # ---------------------------------------------------------------------------

@@ -9,13 +9,15 @@ the mask.  This file is the executable meaning of the ``vec.*`` IR flavor.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.expr import AggSpec, Expr, evaluate
+from ..obs.trace import get_tracer
 
 _I64_MAX = np.iinfo(np.int64).max
 _F32_INF = np.float32(np.inf)
@@ -66,11 +68,34 @@ class VecTable:
         return VecTable(cols, np.arange(cap) < n)
 
     def to_numpy(self) -> Dict[str, np.ndarray]:
-        mask = np.asarray(self.valid)
-        return {k: np.asarray(v)[mask] for k, v in self.cols.items()}
+        """The live rows as host columns, under the ``fetch`` span."""
+        def copy(t: "VecTable") -> Dict[str, np.ndarray]:
+            mask = np.asarray(t.valid)
+            return {k: np.asarray(v)[mask] for k, v in t.cols.items()}
+
+        return fetch(self, copy)
 
     def astuple_cols(self, names: Sequence[str]) -> List[jax.Array]:
         return [self.cols[n] for n in names]
+
+
+def fetch(value: Any, copy: Callable[[Any], Dict[str, np.ndarray]]
+          ) -> Dict[str, np.ndarray]:
+    """A device result brought to the host as numpy columns, traced as
+    ``fetch`` (the host result's ``rows`` and ``bytes``) with two children:
+    ``fetch.wait`` until the device has produced ``value``, then
+    ``fetch.copy``, ``copy(value)``: the device→host copies and any
+    compaction."""
+    tracer = get_tracer()
+    with tracer.span("fetch", cat="fetch") as sp:
+        with tracer.span("fetch.wait", cat="fetch"):
+            jax.block_until_ready(value)
+        with tracer.span("fetch.copy", cat="fetch"):
+            out = copy(value)
+        sp.set(rows=max((len(a) if a.ndim else 1 for a in out.values()),
+                        default=0),
+               bytes=sum(a.nbytes for a in out.values()))
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -67,3 +67,17 @@ def test_spmd_phase_on_four_host_devices():
                           env=subprocess_env(ROOT), cwd=str(ROOT))
     assert proc.returncode == 0, proc.stderr[-4000:]
     assert "SPMD_OK" in proc.stdout
+
+
+def test_first_calls_run_the_plain_executable(monkeypatch):
+    """The guarded first call is traced without the cardinality taps: the
+    traced twin never runs (a call to it would step down the ladder)."""
+    from repro.backends.local import Compiled
+
+    def refuse(*a, **k):
+        raise AssertionError("run_traced called")
+
+    monkeypatch.setattr(Compiled, "run_traced", refuse)
+    records = chip_smoke.run_phases(0.002, 1, jax.devices()[:1])
+    bad = [r for r in records if not r["ok"]]
+    assert not bad, bad

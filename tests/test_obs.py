@@ -427,3 +427,153 @@ def test_exec_calibration_is_not_compile_calibration():
 
     assert EXEC_CALIBRATION is not CALIBRATION
     assert isinstance(EXEC_CALIBRATION, CostCalibration)
+
+
+# ---------------------------------------------------------------------------
+# spans without the cardinality taps, the profiler mirror, operator scopes
+# ---------------------------------------------------------------------------
+
+
+class TestQueryPathSpans:
+    def test_no_cardinalities_runs_the_plain_executable(self, q1_setup,
+                                                        monkeypatch):
+        from repro.backends.local import Compiled
+
+        def refuse(*a, **k):
+            raise AssertionError("run_traced called")
+
+        monkeypatch.setattr(Compiled, "run_traced", refuse)
+        tables, ctx, frame, n_rows, _ = q1_setup
+        cache = PlanCache()
+        ctx.compile(frame, target="local", cache=cache)
+        with tracing(cardinalities=False) as tr:
+            res = ctx.compile(frame, target="local", cache=cache)
+            (out,) = res(ctx.sources(res))
+        assert res.profile is None
+        names = [s.name for s in tr.spans]
+        assert "frontend.compile" in names
+        assert any(n.startswith("execute:") for n in names)
+        assert tr.counters["plan_cache.hit"] == 1
+        # the compile span nests in the frontend's, with the cache outcome
+        by_name = {s.name: s for s in tr.spans}
+        comp = next(s for s in tr.spans if s.name.startswith("compile:"))
+        assert comp.parent_id == by_name["frontend.compile"].span_id
+        assert comp.args["cache"] == "hit"
+
+    def test_sources_and_fetch_spans_nest_with_sizes(self, q1_setup):
+        from repro.frontends.dataflow import _to_numpy
+
+        tables, ctx, frame, _, n_groups = q1_setup
+        res = ctx.compile(frame, target="local", cache=PlanCache())
+        with tracing(cardinalities=False) as tr:
+            got = _to_numpy(res(ctx.sources(res))[0])
+        by_name = {s.name: s for s in tr.spans}
+        src, fetch = by_name["sources"], by_name["fetch"]
+        assert src.args["tables"] == len(ctx.tables) and src.args["bytes"] > 0
+        for child in ("sources.pad", "sources.place"):
+            assert by_name[child].parent_id == src.span_id
+        for child in ("fetch.wait", "fetch.copy"):
+            assert by_name[child].parent_id == fetch.span_id
+        assert fetch.args["rows"] == len(next(iter(got.values())))
+        assert fetch.args["bytes"] == sum(a.nbytes for a in got.values())
+
+    def test_disabled_tracer_creates_no_span_on_the_query_path(
+            self, q1_setup, monkeypatch):
+        import repro.obs.trace as trace_mod
+        from repro.frontends.dataflow import _to_numpy
+
+        def refuse(*a, **k):
+            raise AssertionError("Span created with the tracer off")
+
+        tables, ctx, frame, _, _ = q1_setup
+        assert not get_tracer().enabled
+        monkeypatch.setattr(trace_mod, "Span", refuse)
+        res = ctx.compile(frame, target="local", cache=PlanCache())
+        _to_numpy(res(ctx.sources(res))[0])
+        assert res.profile is None
+
+    def test_importing_the_tracer_imports_no_jax(self):
+        import os
+        import subprocess
+        import sys
+        from pathlib import Path
+
+        code = ("import sys; import repro.obs.trace as t; "
+                "t.Tracer(enabled=False).span('x'); "
+                "assert 'jax' not in sys.modules")
+        src = Path(__file__).resolve().parents[1] / "src"
+        subprocess.run([sys.executable, "-c", code], check=True,
+                       env={**os.environ, "PYTHONPATH": str(src)})
+
+    def test_spans_reach_the_profilers_host_plane(self, q1_setup, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from repro.frontends.dataflow import _to_numpy
+
+        tables, ctx, frame, _, _ = q1_setup
+        res = ctx.compile(frame, target="local", cache=PlanCache())
+        _to_numpy(res(ctx.sources(res))[0])  # compiled before the trace
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tracing(cardinalities=False):
+                _to_numpy(res(ctx.sources(res))[0])
+                with get_tracer().span("annotated"):
+                    get_tracer().record_complete("not.mirrored", "x", 0.0, 0.0)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        names = {ev.name for plane in ProfileData.from_file(path).planes
+                 if plane.name == "/host:CPU"
+                 for line in plane.lines for ev in line.events}
+        assert {"cvm/sources.pad", "cvm/fetch.copy", "cvm/annotated",
+                f"cvm/execute:{res.source.name}"} <= names
+        assert "cvm/not.mirrored" not in names
+
+
+class TestOperatorScopes:
+    SCOPE = r'op_name="[^"]*?/(\d{3}\.vec\.\w+)'
+
+    @staticmethod
+    def _compiled(ctx, frame):
+        res = ctx.compile(frame, target="local", cache=PlanCache())
+        text = res.executable.fn.lower(
+            dict(ctx.sources(res))).compile().as_text()
+        return res, text
+
+    def test_compiled_plan_names_each_op_by_its_instruction(self, q1_setup):
+        import re
+
+        tables, ctx, frame, _, _ = q1_setup
+        res, text = self._compiled(ctx, frame)
+        found = set(re.findall(self.SCOPE, text))
+        body = {f"{i:03d}.{ins.opcode}" for i, ins in enumerate(res.program.body)}
+        assert found and found <= body
+        assert any("GroupAgg" in s or "FusedSelectAgg" in s for s in found)
+
+    def test_scopes_change_only_metadata(self, q1_setup, monkeypatch):
+        """The compiled ops are the same with and without the scopes: the
+        optimized HLO differs only in source locations and ``op_name``."""
+        import contextlib
+        import re
+
+        import repro.backends.emit as emit
+
+        def strip(text):
+            text = re.sub(r"\nFileNames\n.*?\nStackFrames\n(?:\d+ \{[^}]*\}\n)*",
+                          "\n", text, flags=re.S)
+            return re.sub(r",? metadata=\{[^}]*\}", "", text)
+
+        tables, ctx, _, _, _ = q1_setup
+        for q in ("q1", "q12"):
+            frame = tpch.QUERIES[q](ctx)
+            _, scoped = self._compiled(ctx, frame)
+            monkeypatch.setattr(emit, "op_scope",
+                                lambda i, ins: contextlib.nullcontext())
+            _, plain = self._compiled(ctx, frame)
+            monkeypatch.undo()
+            assert re.search(self.SCOPE, scoped)
+            assert not re.search(self.SCOPE, plain)
+            assert strip(scoped) == strip(plain)

@@ -41,6 +41,9 @@ class EvalCtx:
     #: platform the backend runs on — no default, so no path can reach a
     #: kernel with a stale one
     interpret: bool
+    #: the platform the plan is traced for (``jax.Device.platform``): the
+    #: join probe's cost rule reads it
+    platform: str
     sources: Dict[str, Any] = field(default_factory=dict)
     use_kernels: bool = False
     mesh: Any = None            # set by the SPMD backend
@@ -215,7 +218,8 @@ def _dictdecode(ctx, ins, args):
 def _mergejoin(ctx, ins, args):
     return [rt.merge_join_sorted(args[0], args[1], ins.param("left_on"),
                                  ins.param("right_on"), int(ins.param("max_count")),
-                                 key_domains=ins.param("key_domains"))]
+                                 key_domains=ins.param("key_domains"),
+                                 platform=ctx.platform)]
 
 
 @emitter("vec.HashJoinDirect")
@@ -225,7 +229,8 @@ def _hashjoin_direct(ctx, ins, args):
                                 ins.param("right_on"),
                                 int(ins.param("max_count")),
                                 key_domains=ins.param("key_domains"),
-                                num_buckets=int(nb) if nb is not None else None)]
+                                num_buckets=int(nb) if nb is not None else None,
+                                platform=ctx.platform)]
 
 
 @emitter("vec.FusedJoinGroupAgg")

@@ -54,18 +54,20 @@ class LocalBackend:
 
         self.use_kernels = use_kernels
         # kernels run where the jitted program runs: the default backend
-        self.interpret = interpret_on(jax.default_backend())
+        self.platform = jax.default_backend()
+        self.interpret = interpret_on(self.platform)
         self.jit = jit
 
     def compile(self, program: Program) -> Compiled:
         def run(sources: Dict[str, Any], *args: Any) -> List[Any]:
             ctx = EvalCtx(sources=sources, use_kernels=self.use_kernels,
-                          interpret=self.interpret)
+                          interpret=self.interpret, platform=self.platform)
             return evaluate_program(ctx, program, *args)
 
         def run_traced(sources: Dict[str, Any], *args: Any):
             ctx = EvalCtx(sources=sources, use_kernels=self.use_kernels,
-                          interpret=self.interpret, taps={})
+                          interpret=self.interpret, platform=self.platform,
+                          taps={})
             outs = evaluate_program(ctx, program, *args)
             return outs, ctx.taps
 

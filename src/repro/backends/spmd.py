@@ -137,7 +137,8 @@ def _mesh_execute(ctx, ins, args):
             else:
                 local.append(jax.tree_util.tree_map(lambda x: x[0], a))
         inner_ctx = EvalCtx(sources=ctx.sources, use_kernels=ctx.use_kernels,
-                            mesh=mesh, axis=axis, interpret=ctx.interpret)
+                            mesh=mesh, axis=axis, interpret=ctx.interpret,
+                            platform=ctx.platform)
         outs = evaluate_spmd_program(inner_ctx, p, *local)
         return tuple(jax.tree_util.tree_map(lambda x: jnp.asarray(x)[None], o)
                      for o in outs)
@@ -290,7 +291,8 @@ class SpmdBackend:
         self.axis = axis
         self.use_kernels = use_kernels
         # kernels run on the mesh's devices, whatever the default backend
-        self.interpret = interpret_on(mesh.devices.flat[0].platform)
+        self.platform = mesh.devices.flat[0].platform
+        self.interpret = interpret_on(self.platform)
         self.collectives = collectives
         self.jit = jit
         # standalone use still rewrites here; the compilation driver runs the
@@ -305,12 +307,14 @@ class SpmdBackend:
 
         def run(sources: Dict[str, Any], *args: Any) -> List[Any]:
             ctx = EvalCtx(sources=sources, use_kernels=self.use_kernels,
-                          mesh=self.mesh, interpret=self.interpret)
+                          mesh=self.mesh, interpret=self.interpret,
+                          platform=self.platform)
             return evaluate_spmd_program(ctx, program, *args)
 
         def run_traced(sources: Dict[str, Any], *args: Any):
             ctx = EvalCtx(sources=sources, use_kernels=self.use_kernels,
-                          mesh=self.mesh, interpret=self.interpret, taps={})
+                          mesh=self.mesh, interpret=self.interpret,
+                          platform=self.platform, taps={})
             outs = evaluate_spmd_program(ctx, program, *args)
             return outs, ctx.taps
 

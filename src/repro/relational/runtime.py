@@ -435,13 +435,114 @@ def group_agg_direct(t: VecTable, keys: Sequence[str], aggs: Sequence[AggSpec],
     return compact(buckets, max_groups)
 
 
+#: build keys a row of :func:`probe_descent`'s tables: one lane row of a
+#: TPU vector register
+DESCENT_FANOUT = 128
+#: probe rows :func:`probe_descent` takes a step: each level gathers a
+#: ``[DESCENT_CHUNK, DESCENT_FANOUT]`` block of rows
+DESCENT_CHUNK = 1 << 19
+#: descent chosen on a TPU when ``nl * ceil(log2(nr + 1))``, the probe
+#: rows the binary search gathers over all its rounds, reaches this many
+#: times the ``nl + nr`` rows the descent reads: below it, building the
+#: descent's tables over the build side costs more than the search (on a
+#: v5e the two cross near a few thousand probe rows into 15M build rows)
+DESCENT_PROBE_C = 0.01
+
+
+def probe_by_descent(nl: int, nr: int, platform: str) -> bool:
+    """Whether :func:`merge_join_sorted` probes ``nl`` rows into ``nr``
+    build rows with :func:`probe_descent` (else :func:`probe_search`), for
+    a plan traced for ``platform``.  A pure function of static shapes: on
+    a TPU each binary-search round is a random gather over every probe
+    row, and the descent gathers a row of build keys per probe row only
+    once a level; on the CPU gathers are cheap, so it always searches."""
+    if platform != "tpu":
+        return False
+    rounds = int(nr).bit_length()  # ceil(log2(nr + 1))
+    return nl * rounds >= DESCENT_PROBE_C * (nl + nr)
+
+
+def probe_search(rk: jax.Array, rvalid: jax.Array, lk: jax.Array) -> jax.Array:
+    """For each probe key of ``lk``, the first valid row of the key-sorted
+    build keys ``rk`` that holds it, or ``len(rk)`` where none does: a
+    binary search (``searchsorted``, one gather of a build key per probe
+    row in each of its ``ceil(log2(nr + 1))`` rounds)."""
+    nr = rk.shape[0]
+    rk = jnp.where(rvalid, rk, jnp.iinfo(jnp.int32).max)
+    idx = jnp.clip(jnp.searchsorted(rk, lk), 0, nr - 1)
+    hit = (rk[idx] == lk) & rvalid[idx]
+    return jnp.where(hit, idx, nr)
+
+
+def probe_descent(rk: jax.Array, rvalid: jax.Array, lk: jax.Array) -> jax.Array:
+    """:func:`probe_search`'s answer from a descent through a static tree
+    of ``B = DESCENT_FANOUT`` keys a node: ``ceil(log_B(nr))`` levels, each
+    one gather of a row of ``B`` build keys per probe row and a count of
+    the keys below the probe key, in place of a gather per binary-search
+    round.
+
+    The leaves are the build keys (the sentinel on invalid rows, as
+    ``probe_search`` reads them) in rows of ``B``; each level above holds
+    the first key of every row below it.  Each level keeps the first key
+    not below the probe key (in the row, else the one the level above
+    found), which at the leaves is the key at the lower bound.  Probe rows
+    go ``DESCENT_CHUNK`` at a time, so a step's row block stays bounded."""
+    nr, nl = rk.shape[0], lk.shape[0]
+    fan = DESCENT_FANOUT
+    big = jnp.iinfo(jnp.int32).max
+    keys = jnp.where(rvalid, rk, big)
+    levels = [jnp.concatenate([keys, jnp.full((-nr % fan,), big, jnp.int32)])
+              .reshape(-1, fan)]
+    while levels[0].shape[0] > 1:
+        first = levels[0][:, 0]
+        levels.insert(0, jnp.concatenate(
+            [first, jnp.full((-first.shape[0] % fan,), big, jnp.int32)]).reshape(-1, fan))
+    table = jnp.concatenate(levels)
+    start = jnp.asarray(np.cumsum([0] + [t.shape[0] for t in levels[:-1]]), jnp.int32)
+    # a probe key equal to the sentinel matches only a valid row holding it
+    at = jnp.argmax(keys == big)
+    big_ok = (keys[at] == big) & rvalid[at]
+    lane = jnp.arange(fan, dtype=jnp.int32)
+    c = min(DESCENT_CHUNK, max(nl, 1))
+    steps = -(-nl // c)
+    xs = jnp.concatenate([lk, jnp.zeros((steps * c - nl,), jnp.int32)])
+
+    def level(k, carry):
+        x, node, key, _ = carry
+        row = table[start[k] + node]
+        below = jnp.sum(row < x[:, None], axis=1, dtype=jnp.int32)
+        key = jnp.where(below < fan, jnp.sum(
+            jnp.where(lane == below[:, None], row, 0), axis=1), key)
+        return x, node * fan + jnp.maximum(below - 1, 0), key, node * fan + below
+
+    def step(i, out):
+        x = jax.lax.dynamic_slice_in_dim(xs, i * c, c)
+        zero = jnp.zeros((c,), jnp.int32)
+        _, _, key, idx = jax.lax.fori_loop(
+            0, len(levels), level, (x, zero, jnp.full((c,), big, jnp.int32), zero))
+        hit = (key == x) & ((x != big) | big_ok)
+        return jax.lax.dynamic_update_slice_in_dim(out, jnp.where(hit, idx, nr), i * c, 0)
+
+    return jax.lax.fori_loop(0, steps, step, jnp.zeros((steps * c,), jnp.int32))[:nl]
+
+
 def merge_join_sorted(left: VecTable, right: VecTable, left_on: Sequence[str],
                       right_on: Sequence[str], max_count: int,
                       key_domains: Optional[Sequence[Tuple[int, int]]] = None,
-                      ) -> VecTable:
+                      platform: Optional[str] = None) -> VecTable:
     """PK-FK inner equi-join: ``right`` must be key-sorted with unique keys.
 
-    searchsorted + gather — the TPU-native rewrite of Build/ProbeHTable.
+    Probe + gather — the TPU-native rewrite of Build/ProbeHTable.  Each
+    left row takes the first valid right row with its key (a duplicate
+    build key's first row wins).  Two probes give that answer, chosen at
+    trace time by :func:`probe_by_descent` from the static row counts and
+    the ``platform`` the plan is traced for (default: JAX's default
+    backend): :func:`probe_search`, a binary search whose every round
+    gathers over all left rows, or :func:`probe_descent`, a descent
+    through 128-key rows that gathers a row per left row once a level.
+    Each choice is counted in ``repro.obs`` as ``merge_join.probe_search``
+    or ``merge_join.probe_descent``, once per traced join.
+
     Multi-column keys are packed with catalog ``key_domains`` when the
     lowering provides them (static overflow check — overpacking raises),
     otherwise with bounds traced jointly from both sides (collision-free
@@ -458,11 +559,15 @@ def merge_join_sorted(left: VecTable, right: VecTable, left_on: Sequence[str],
     else:
         lk = left.cols[left_on[0]].astype(jnp.int32)
         rk = right.cols[right_on[0]].astype(jnp.int32)
-    sentinel = jnp.iinfo(jnp.int32).max
-    rk = jnp.where(right.valid, rk, sentinel)
-    idx = jnp.searchsorted(rk, lk)
-    idx_c = jnp.clip(idx, 0, right.capacity - 1)
-    match = (rk[idx_c] == lk) & left.valid
+    cap_r = right.capacity
+    if probe_by_descent(left.capacity, cap_r, platform or jax.default_backend()):
+        get_tracer().counter("merge_join.probe_descent")
+        idx = probe_descent(rk, right.valid, lk)
+    else:
+        get_tracer().counter("merge_join.probe_search")
+        idx = probe_search(rk, right.valid, lk)
+    match = (idx < cap_r) & left.valid
+    idx_c = jnp.minimum(idx, cap_r - 1)
 
     out = dict(left.cols)
     lnames = set(left.cols)
@@ -533,7 +638,8 @@ def _direct_probe(left: VecTable, right: VecTable, right_on: Sequence[str],
 def hash_join_direct(left: VecTable, right: VecTable, left_on: Sequence[str],
                      right_on: Sequence[str], max_count: int,
                      key_domains: Optional[Sequence[Tuple[int, int]]] = None,
-                     num_buckets: Optional[int] = None) -> VecTable:
+                     num_buckets: Optional[int] = None,
+                     platform: Optional[str] = None) -> VecTable:
     """Sort-free PK-FK inner equi-join via a dense direct table.
 
     The O(n) sibling of :func:`merge_join_sorted` — no sort of the build
@@ -550,7 +656,8 @@ def hash_join_direct(left: VecTable, right: VecTable, left_on: Sequence[str],
       from both sides; when the traced domain product exceeds the static
       ``num_buckets`` budget the instruction falls back to the sorted merge
       join *inside* the trace (``lax.cond``), so the plan stays valid for
-      any data.
+      any data.  ``platform`` chooses that join's probe, as in
+      :func:`merge_join_sorted`.
     """
     if key_domains is not None:
         nb = 1
@@ -593,7 +700,8 @@ def hash_join_direct(left: VecTable, right: VecTable, left_on: Sequence[str],
     def _sorted(args):
         l, r = args
         rs = sort_by_key(r, right_on)
-        return merge_join_sorted(l, rs, left_on, right_on, l.capacity)
+        return merge_join_sorted(l, rs, left_on, right_on, l.capacity,
+                                 platform=platform)
 
     joined = jax.lax.cond(fits, _direct, _sorted, (left, right))
     if max_count != left.capacity:

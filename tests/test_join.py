@@ -509,3 +509,267 @@ class TestSpmdJoin:
         assert spmd_join_results["hash_ok"]
         assert "vec.MergeJoinSorted" in spmd_join_results["sorted_ops"]
         assert "vec.HashJoinDirect" in spmd_join_results["hash_ops"]
+
+
+# ---------------------------------------------------------------------------
+# merge_join_sorted's two probes: binary search and descent
+# ---------------------------------------------------------------------------
+
+I32_MAX = np.iinfo(np.int32).max
+I32_MIN = np.iinfo(np.int32).min
+
+
+def _probe_reference(rk, rvalid, lk):
+    """First valid build row holding each probe key, ``len(rk)`` if none:
+    ``np.searchsorted`` over the valid build rows (key-sorted)."""
+    rows = np.flatnonzero(rvalid)
+    keys = rk[rows]
+    at = np.searchsorted(keys, lk)
+    found = at < len(keys)
+    found[found] = keys[at[found]] == lk[found]
+    return np.where(found, rows[np.minimum(at, max(len(rows) - 1, 0))]
+                    if len(rows) else 0, len(rk)).astype(np.int32)
+
+
+def _probe_case(name):
+    """(build keys, build validity, probe keys) of one case; invalid build
+    rows trail the valid ones holding the sentinel, as ``sort_by_key`` and
+    ``merge_join_sorted`` leave them."""
+    rng = np.random.default_rng(11)
+    if name == "unique_with_misses":
+        rk = np.arange(0, 80, 2, dtype=np.int32)
+        return rk, np.ones(40, bool), rng.integers(-3, 84, 100).astype(np.int32)
+    if name == "invalid_build_sentinel":
+        rk = np.concatenate([np.arange(10, dtype=np.int32),
+                             np.full(6, I32_MAX, np.int32)])
+        return rk, np.arange(16) < 10, rng.integers(0, 12, 50).astype(np.int32)
+    if name == "extreme_probe_keys":
+        rk = np.array([I32_MIN, -5, 0, 7, I32_MAX, I32_MAX, I32_MAX], np.int32)
+        lk = np.array([I32_MAX, I32_MIN, 7, 8, I32_MAX, -5, I32_MIN + 1], np.int32)
+        return rk, np.arange(7) < 5, lk
+    if name == "extreme_probe_keys_no_match":
+        rk = np.concatenate([np.arange(1, 9, dtype=np.int32),
+                             np.full(4, I32_MAX, np.int32)])
+        lk = np.array([I32_MAX, I32_MIN, I32_MAX, 3, I32_MIN], np.int32)
+        return rk, np.arange(12) < 8, lk
+    if name == "valid_sentinel_key":
+        rk = np.array([2, 5, I32_MAX], np.int32)
+        return rk, np.ones(3, bool), np.array([I32_MAX, 5, 3, I32_MIN], np.int32)
+    if name == "sentinel_probe_no_sentinel_key":
+        rk = np.arange(1, 10, dtype=np.int32)
+        return rk, np.ones(9, bool), np.array([I32_MAX, 9, 10, 0, 1], np.int32)
+    if name == "no_valid_build_rows":
+        return (np.full(8, I32_MAX, np.int32), np.zeros(8, bool),
+                np.array([0, 1, I32_MAX, I32_MIN], np.int32))
+    if name == "fewer_probe_than_build":
+        rk = np.sort(rng.choice(1000, 300, replace=False)).astype(np.int32)
+        return rk, np.ones(300, bool), rng.integers(0, 1000, 20).astype(np.int32)
+    if name == "more_probe_than_build":
+        rk = np.sort(rng.choice(50, 12, replace=False)).astype(np.int32)
+        return rk, np.ones(12, bool), rng.integers(0, 50, 400).astype(np.int32)
+    if name == "duplicate_build_keys":
+        rk = np.array([1, 3, 3, 3, 5, 5, 8, I32_MAX, I32_MAX], np.int32)
+        lk = np.array([3, 5, 8, 1, 2, 3, 5, 9], np.int32)
+        return rk, np.arange(9) < 7, lk
+    raise KeyError(name)
+
+
+PROBE_CASES = ("unique_with_misses", "invalid_build_sentinel",
+               "extreme_probe_keys", "extreme_probe_keys_no_match",
+               "valid_sentinel_key", "sentinel_probe_no_sentinel_key",
+               "no_valid_build_rows", "fewer_probe_than_build",
+               "more_probe_than_build", "duplicate_build_keys")
+
+
+@pytest.mark.parametrize("probe", ["probe_descent", "probe_search"])
+@pytest.mark.parametrize("case", PROBE_CASES)
+def test_probe_matches_reference(probe, case):
+    """Both probes give each probe key the first valid build row holding
+    it, and ``len(rk)`` where none does; an invalid build row never
+    matches, even a probe key equal to its sentinel."""
+    import jax.numpy as jnp
+    rk, rvalid, lk = _probe_case(case)
+    got = getattr(rt, probe)(jnp.asarray(rk), jnp.asarray(rvalid), jnp.asarray(lk))
+    np.testing.assert_array_equal(np.asarray(got), _probe_reference(rk, rvalid, lk))
+
+
+@pytest.mark.parametrize("fanout,chunk,nl,nr", [
+    (4, 7, 50, 1), (4, 7, 50, 4), (4, 7, 50, 5), (4, 7, 49, 16),
+    (4, 7, 49, 17), (4, 8, 64, 65), (4, 1000, 300, 257), (3, 5, 31, 100),
+    (128, 1 << 19, 500, 16385),
+])
+def test_descent_tree_shapes(fanout, chunk, nl, nr, monkeypatch):
+    """The descent answers like the reference whatever the tree's depth,
+    the build side's padding to whole rows and the last partial chunk of
+    probe rows: ``nr`` on both sides of a power of the fanout, ``nl`` on
+    both sides of a multiple of the chunk."""
+    import jax.numpy as jnp
+    monkeypatch.setattr(rt, "DESCENT_FANOUT", fanout)
+    monkeypatch.setattr(rt, "DESCENT_CHUNK", chunk)
+    rng = np.random.default_rng(nl * 1000 + nr)
+    valid = nr - nr // 5
+    rk = np.concatenate([np.sort(rng.choice(4 * nr + 8, valid, replace=False)),
+                         np.full(nr - valid, I32_MAX)]).astype(np.int32)
+    rvalid = np.arange(nr) < valid
+    lk = rng.integers(-2, 4 * nr + 10, nl).astype(np.int32)
+    lk[:2] = (I32_MAX, I32_MIN)
+    got = rt.probe_descent(jnp.asarray(rk), jnp.asarray(rvalid), jnp.asarray(lk))
+    want = _probe_reference(rk, rvalid, lk)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("probe", ["probe_descent", "probe_search"])
+def test_probe_composite_keys(probe):
+    """Composite keys packed with ``key_domains`` probe like single keys."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(5)
+    domains = ((0, 9), (100, 149))
+    grid = np.stack(np.meshgrid(np.arange(10), np.arange(100, 150),
+                                indexing="ij"), -1).reshape(-1, 2)
+    build = grid[np.sort(rng.choice(len(grid), 200, replace=False))]
+    right = VecTable.from_numpy({"a": build[:, 0].astype(np.int32),
+                                 "b": build[:, 1].astype(np.int32)}, 256)
+    left = VecTable.from_numpy(
+        {"a": rng.integers(0, 10, 300).astype(np.int32),
+         "b": rng.integers(100, 150, 300).astype(np.int32)}, 300)
+    rk = rt._composite_key(right, ("a", "b"), key_domains=domains)
+    lk = rt._composite_key(left, ("a", "b"), key_domains=domains)
+    got = getattr(rt, probe)(rk, right.valid, lk)
+    want = _probe_reference(np.asarray(rk), np.asarray(right.valid), np.asarray(lk))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert (want < 256).sum() > 50  # the case has matches as well as misses
+
+
+def _join_case(name):
+    """(left, right, left_on, right_on, max_count, key_domains) of one
+    merge-join case; the right side is key-sorted."""
+    rng = np.random.default_rng(17)
+    y = lambda m: rng.normal(size=m).astype(np.float32)  # noqa: E731
+    if name in ("int_keys", "compacted", "no_valid_left", "no_valid_right"):
+        left = VecTable.from_numpy({"k": rng.integers(0, 80, 300).astype(np.int32),
+                                    "x": y(300)}, 320)
+        right = VecTable.from_numpy({"k2": np.arange(0, 120, 3, dtype=np.int32),
+                                     "y": y(40)}, 48)
+        if name == "no_valid_left":
+            left = VecTable(left.cols, np.zeros(320, bool))
+        if name == "no_valid_right":
+            right = VecTable(right.cols, np.zeros(48, bool))
+        cap = 128 if name == "compacted" else 320
+        return left, right, ("k",), ("k2",), cap, None
+    if name == "duplicate_build_keys":
+        left = VecTable.from_numpy({"k": np.array([3, 3, 1, 2], np.int32),
+                                    "x": y(4)}, 4)
+        right = VecTable.from_numpy({"k": np.array([1, 3, 3, 3], np.int32),
+                                     "y": y(4)}, 6)
+        return left, right, ("k",), ("k",), 4, None
+    grid = np.stack(np.meshgrid(np.arange(6), np.arange(40), indexing="ij"),
+                    -1).reshape(-1, 2)[::3]
+    right = VecTable.from_numpy({"a2": grid[:, 0].astype(np.int32),
+                                 "b2": grid[:, 1].astype(np.int32),
+                                 "y": y(len(grid))}, 96)
+    left = VecTable.from_numpy({"a": rng.integers(0, 6, 200).astype(np.int32),
+                                "b": rng.integers(0, 40, 200).astype(np.int32),
+                                "x": y(200)}, 256)
+    domains = ((0, 5), (0, 39)) if name == "composite_domains" else None
+    return left, right, ("a", "b"), ("a2", "b2"), 256, domains
+
+
+@pytest.mark.parametrize("case", ["int_keys", "compacted", "no_valid_left",
+                                  "no_valid_right", "duplicate_build_keys",
+                                  "composite_domains", "composite_traced_bounds"])
+def test_merge_join_same_under_both_probes(case, monkeypatch):
+    """``merge_join_sorted`` gives the same table whichever probe the rule
+    picks: the same valid rows, in the same order, with the same values."""
+    left, right, left_on, right_on, cap, domains = _join_case(case)
+    right = rt.sort_by_key(right, right_on)
+    tables = {}
+    for descent in (False, True):
+        monkeypatch.setattr(rt, "probe_by_descent",
+                            lambda nl, nr, platform, d=descent: d)
+        tables[descent] = rt.merge_join_sorted(left, right, left_on, right_on, cap,
+                                               key_domains=domains)
+    search, desc = _rows(tables[False]), _rows(tables[True])
+    assert set(search) == set(desc)
+    for k in search:
+        np.testing.assert_array_equal(desc[k], search[k], err_msg=k)
+    np.testing.assert_array_equal(np.asarray(tables[True].valid),
+                                  np.asarray(tables[False].valid))
+
+
+@pytest.mark.parametrize("nl,nr,platform,descent", [
+    (60_000_000, 15_000_064, "tpu", True),
+    (60_000_000, 2_000_000, "tpu", True),
+    (15_000_064, 15_000_064, "tpu", True),
+    (1_000, 15_000_064, "tpu", False),
+    (60_000_000, 15_000_064, "cpu", False),
+    (60_000_000, 2_000_000, "cpu", False),
+    (15_000_064, 15_000_064, "cpu", False),
+    (1_000, 15_000_064, "cpu", False),
+])
+def test_probe_rule(nl, nr, platform, descent):
+    """The probe is a pure function of the static row counts and the
+    platform: search on the CPU always, the descent on a TPU for the
+    benchmark's joins, search there for a few rows into a large build
+    side."""
+    assert rt.probe_by_descent(nl, nr, platform) is descent
+
+
+@pytest.mark.parametrize("descent", [False, True])
+def test_probe_counters(descent, monkeypatch):
+    """One ``merge_join.probe_*`` count per traced join, none per call."""
+    import jax
+    from repro.obs.trace import tracing
+    monkeypatch.setattr(rt, "probe_by_descent", lambda nl, nr, platform: descent)
+    left, right, left_on, right_on, cap, _ = _join_case("int_keys")
+    right = rt.sort_by_key(right, right_on)
+
+    def two_joins(left, right):
+        a = rt.merge_join_sorted(left, right, left_on, right_on, cap)
+        b = rt.merge_join_sorted(left, right, left_on, right_on, 64)
+        return a, b
+
+    fn = jax.jit(two_joins)
+    with tracing(cardinalities=False) as tracer:
+        for _ in range(3):
+            jax.block_until_ready(fn(left, right))
+    taken, other = (("merge_join.probe_descent", "merge_join.probe_search") if descent
+                    else ("merge_join.probe_search", "merge_join.probe_descent"))
+    assert tracer.counters.get(taken) == 2
+    assert other not in tracer.counters
+
+
+@pytest.fixture(scope="module")
+def tpch_small():
+    from repro.relational import tpch
+    tables = tpch.generate(sf=0.002, seed=5)
+    return tables, tpch.make_context(tables, pad_to=256)
+
+
+@pytest.mark.parametrize("qname", ["q1", "q4", "q6", "q12", "q14", "q19"])
+def test_tpch_same_answers_with_descent_probe(qname, tpch_small, monkeypatch):
+    """The benchmark's six queries (their ``relational/tpch.py`` siblings)
+    answer bit for bit the same with the descent forced, and every join of
+    Q4, Q12, Q14 and Q19 takes it."""
+    from repro.obs.trace import tracing
+    from repro.relational import tpch
+    tables, ctx = tpch_small
+
+    def answer():
+        plan = ctx.compile(tpch.QUERIES[qname](ctx), cache=False)
+        (out,) = plan(ctx.sources(plan))
+        out = out.to_numpy() if isinstance(out, VecTable) else out
+        return plan, {k: np.asarray(v) for k, v in out.items()}
+
+    _, want = answer()
+    monkeypatch.setattr(rt, "probe_by_descent", lambda nl, nr, platform: True)
+    with tracing(cardinalities=False) as tracer:
+        plan, got = answer()
+    assert set(got) == set(want)
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+    joins = plan.program.opcodes().count("vec.MergeJoinSorted")
+    assert joins == (0 if qname in ("q1", "q6") else 1)
+    assert tracer.counters.get("merge_join.probe_descent", 0) == joins
+    assert "merge_join.probe_search" not in tracer.counters
+    tpch.assert_result_close(got, tpch.REFERENCES[qname](tables),
+                             tpch.GROUP_KEYS.get(qname, ()))

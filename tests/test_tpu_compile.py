@@ -171,3 +171,23 @@ def test_q1_local_program_with_kernels_compiles(one_chip):
                      {k: a.dtype for k, a in vt.cols.items()})
         for name, vt in ctx.sources().items()}
     assert KERNEL in fn.lower(shapes).compile().as_text()
+
+
+def test_join_probes_compile_small(one_chip):
+    """Both join probes compile at Q12's SF10 shapes (60M lineitem rows
+    into 15M orders keys).  A TPU executable's code sits in HBM beside the
+    tables, and a sort or a scatter compiles to megabytes of it, so the
+    descent holds neither and stays within 1 MiB of the binary search."""
+    from repro.relational import runtime as rt
+
+    rk = jax.ShapeDtypeStruct((ORDERS_ROWS,), jnp.int32, sharding=one_chip)
+    rvalid = jax.ShapeDtypeStruct((ORDERS_ROWS,), jnp.bool_, sharding=one_chip)
+    lk = jax.ShapeDtypeStruct((LINEITEM_ROWS,), jnp.int32, sharding=one_chip)
+    code = {}
+    for probe in (rt.probe_search, rt.probe_descent):
+        compiled = jax.jit(probe).lower(rk, rvalid, lk).compile()
+        code[probe.__name__] = compiled.memory_analysis().generated_code_size_in_bytes
+        if probe is rt.probe_descent:
+            hlo = compiled.as_text()
+            assert " sort(" not in hlo and " scatter(" not in hlo
+    assert code["probe_descent"] - code["probe_search"] < 1 << 20, code
